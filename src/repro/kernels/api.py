@@ -342,8 +342,12 @@ class SlicedTensor:
 
 BACKENDS = ("pallas", "interpret", "xla", "pimsab")
 
-# CPU container: oracles by default; TPU target: "pallas".  Overridable per
-# process via set_default_backend and per scope via use_backend.
+# With no scope active, registry calls run the oracles.  Nothing switches
+# backends by itself: an entry point that wants the kernels names "pallas"
+# (``launch/serve.py --backend``, ``chip_smoke.py``), and a kernel under
+# "pallas" on a host without a TPU raises rather than falling back.
+# Overridable per process via set_default_backend and per scope via
+# use_backend.
 _default_backend = "xla"
 _backend_stack: contextvars.ContextVar[Tuple[str, ...]] = contextvars.ContextVar(
     "repro_kernel_backend_stack", default=()
@@ -675,31 +679,37 @@ def quantized_matmul(
 # ---------------------------------------------------------------------------
 
 
-def htree_reduce(x: jnp.ndarray, *, block_d: int = 512) -> jnp.ndarray:
+def _tiling(**knobs) -> Optional[Dict[str, Any]]:
+    """``pallas_kwargs`` holding the tiling knobs a caller set; the kernels
+    own the defaults (``None``: all of them)."""
+    return {k: v for k, v in knobs.items() if v is not None} or None
+
+
+def htree_reduce(x: jnp.ndarray) -> jnp.ndarray:
     """(N, D) → (D,) log-depth H-tree reduction on the active backend."""
-    return dispatch("htree_reduce", x, pallas_kwargs={"block_d": block_d})
+    return dispatch("htree_reduce", x)
 
 
 def rglru_scan(
     a: jnp.ndarray, b: jnp.ndarray, h0: jnp.ndarray, *,
-    block_t: int = 256, block_w: int = 512,
+    block_t: Optional[int] = None, block_w: Optional[int] = None,
 ) -> jnp.ndarray:
     """RG-LRU linear recurrence h_t = a_t·h_{t-1} + b_t on the active backend."""
     return dispatch(
         "rglru_scan", a, b, h0,
-        pallas_kwargs={"block_t": block_t, "block_w": block_w},
+        pallas_kwargs=_tiling(block_t=block_t, block_w=block_w),
     )
 
 
-def ewise_add(x: jnp.ndarray, y: jnp.ndarray, *, block: int = 512) -> jnp.ndarray:
+def ewise_add(x: jnp.ndarray, y: jnp.ndarray, *, block: Optional[int] = None) -> jnp.ndarray:
     """Elementwise x + y (any matching shapes) on the active backend."""
-    return dispatch("ewise_add", x, y, pallas_kwargs={"block": block})
+    return dispatch("ewise_add", x, y, pallas_kwargs=_tiling(block=block))
 
 
-def relu(x: jnp.ndarray, *, block: int = 512) -> jnp.ndarray:
+def relu(x: jnp.ndarray, *, block: Optional[int] = None) -> jnp.ndarray:
     """Elementwise max(x, 0) on the active backend (PIMSAB: CmpGE + predicated
     copy through the PE mask latch)."""
-    return dispatch("relu", x, pallas_kwargs={"block": block})
+    return dispatch("relu", x, pallas_kwargs=_tiling(block=block))
 
 
 def conv2d(
@@ -710,52 +720,52 @@ def conv2d(
     padding: int = 0,
     x_bits: Optional[int] = None,
     w_bits: Optional[int] = None,
-    block: Optional[Tuple[int, int]] = None,
+    block: Optional[Tuple[int, int, int]] = None,
 ) -> jnp.ndarray:
     """2-D convolution ``(N, C, H, W) × (OC, C, KH, KW) → (N, OC, OH, OW)``
     on the active backend.
 
     Integer inputs accumulate in int32 (wrapping — bit-exact across
     backends); the pimsab backend lowers via im2col onto the ``mac`` gemm
-    pipeline.  ``x_bits``/``w_bits`` are static precision hints for the
-    pimsab lowering (program mode cannot calibrate from values); when they
-    bound the operand magnitudes — or saturate at 32, where wraparound
-    matches int32 — results stay bit-exact.
+    pipeline.  ``x_bits``/``w_bits`` are static precision hints: the pimsab
+    lowering sizes its fields from them (program mode cannot calibrate from
+    values) and the Pallas kernel its int8 slice counts; when they bound
+    the operand magnitudes — or saturate at 32, where wraparound matches
+    int32 — results stay bit-exact.
     """
     return dispatch(
         "conv2d", x, w, stride=stride, padding=padding,
-        x_bits=x_bits, w_bits=w_bits,
-        pallas_kwargs=None if block is None else {"block": block},
+        x_bits=x_bits, w_bits=w_bits, pallas_kwargs=_tiling(block=block),
     )
 
 
 def maxpool2d(
     x: jnp.ndarray, *, window: int = 2, stride: Optional[int] = None,
-    block: int = 512,
+    block: Optional[int] = None,
 ) -> jnp.ndarray:
     """Window max pooling ``(N, C, H, W) → (N, C, OH, OW)`` (no padding;
     ``stride`` defaults to ``window``).  PIMSAB folds the window with CmpGE +
     masked copies — the same predicated-execution idiom relu uses."""
     return dispatch(
         "maxpool2d", x, window=window, stride=stride,
-        pallas_kwargs={"block": block},
+        pallas_kwargs=_tiling(block=block),
     )
 
 
 def avgpool2d(
-    x: jnp.ndarray, *, window: int = 2, block: int = 512
+    x: jnp.ndarray, *, window: int = 2, block: Optional[int] = None
 ) -> jnp.ndarray:
     """Window average pooling, stride == window.  Integer inputs floor-divide
     by the window count — on PIMSAB the divide is free: the store reads the
     sum accumulator at a wordline offset (arithmetic right shift), so the
     window count must be a power of two there."""
-    return dispatch("avgpool2d", x, window=window, pallas_kwargs={"block": block})
+    return dispatch("avgpool2d", x, window=window, pallas_kwargs=_tiling(block=block))
 
 
-def global_avgpool(x: jnp.ndarray, *, block: int = 512) -> jnp.ndarray:
+def global_avgpool(x: jnp.ndarray, *, block: Optional[int] = None) -> jnp.ndarray:
     """Global spatial average ``(N, C, H, W) → (N, C)`` (integer inputs
     floor-divide by H·W; a power of two on the pimsab backend)."""
-    return dispatch("global_avgpool", x, pallas_kwargs={"block": block})
+    return dispatch("global_avgpool", x, pallas_kwargs=_tiling(block=block))
 
 
 def int_matmul(
@@ -764,26 +774,27 @@ def int_matmul(
     *,
     x_bits: Optional[int] = None,
     w_bits: Optional[int] = None,
-    block: Optional[Tuple[int, int]] = None,
+    block: Optional[Tuple[int, int, int]] = None,
 ) -> jnp.ndarray:
     """Raw-integer ``(M, K) @ (K, N)`` with int32 accumulation — the
     network-head matmul for activations that arrive as another kernel's
     integer output (no :class:`SlicedTensor` slice stacks involved)."""
     return dispatch(
         "int_matmul", x, w, x_bits=x_bits, w_bits=w_bits,
-        pallas_kwargs=None if block is None else {"block": block},
+        pallas_kwargs=_tiling(block=block),
     )
 
 
 def attention_qk(
     q: jnp.ndarray, k: jnp.ndarray, *,
     q_bits: Optional[int] = None, k_bits: Optional[int] = None,
-    out_bits: Optional[int] = None, block_m: int = 128,
+    out_bits: Optional[int] = None, block: Optional[Tuple[int, int, int]] = None,
 ) -> jnp.ndarray:
     """Attention scores ``(M, D) q × (T, D) k → (M, T) int32`` (q·Kᵀ) on the
     active backend.
 
-    ``q_bits``/``k_bits`` are static precision hints for the pimsab lowering.
+    ``q_bits``/``k_bits`` are static precision hints (pimsab field widths,
+    Pallas int8 slice counts).
     ``out_bits`` is the caller's promise that every score fits that many
     signed bits: in program mode it clamps the score field width so the
     downstream fixed-point softmax scratch stays small (scores that overflow
@@ -793,13 +804,13 @@ def attention_qk(
     """
     return dispatch(
         "attention_qk", q, k, q_bits=q_bits, k_bits=k_bits, out_bits=out_bits,
-        pallas_kwargs={"block_m": block_m},
+        pallas_kwargs=_tiling(block=block),
     )
 
 
 def softmax_fixedpoint(
     x: jnp.ndarray, *, in_frac: int, in_bits: Optional[int] = None,
-    block_r: int = 128,
+    block_r: Optional[int] = None,
 ) -> jnp.ndarray:
     """Bit-exact fixed-point row softmax of ``(R, T)`` integers on the active
     backend.
@@ -813,14 +824,14 @@ def softmax_fixedpoint(
     """
     return dispatch(
         "softmax_fixedpoint", x, in_frac=in_frac, in_bits=in_bits,
-        pallas_kwargs={"block_r": block_r},
+        pallas_kwargs=_tiling(block_r=block_r),
     )
 
 
 def attention_pv(
     p: jnp.ndarray, v: jnp.ndarray, *, shift: Optional[int] = None,
     p_bits: Optional[int] = None, v_bits: Optional[int] = None,
-    block_m: int = 128,
+    block: Optional[Tuple[int, int, int]] = None,
 ) -> jnp.ndarray:
     """Probability-weighted value mix ``(M, T) p × (T, Dv) v → (M, Dv)
     int32`` with the accumulator arithmetically shifted right by ``shift``
@@ -832,14 +843,14 @@ def attention_pv(
     if shift is not None:
         kwargs["shift"] = shift
     return dispatch(
-        "attention_pv", p, v, pallas_kwargs={"block_m": block_m}, **kwargs
+        "attention_pv", p, v, pallas_kwargs=_tiling(block=block), **kwargs
     )
 
 
 def decode_gemv(
     w: jnp.ndarray, x: jnp.ndarray, *,
     w_bits: Optional[int] = None, x_bits: Optional[int] = None,
-    block_m: int = 128,
+    block: Optional[Tuple[int, int, int]] = None,
 ) -> jnp.ndarray:
     """Single-token decode projection ``(M, K) w × (K,) x → (M,) int32`` on
     the active backend.  The pimsab lowering sends the shared activation
@@ -847,7 +858,7 @@ def decode_gemv(
     instead of broadcasting it through the NoC."""
     return dispatch(
         "decode_gemv", w, x, w_bits=w_bits, x_bits=x_bits,
-        pallas_kwargs={"block_m": block_m},
+        pallas_kwargs=_tiling(block=block),
     )
 
 

@@ -14,14 +14,19 @@ restoring-division loop (no int division on the VPU, and it mirrors the
 bit-serial machine's masked conditional-subtract divider), and every ``>>``
 is arithmetic, matching the pimsab shifted-window reads bit for bit.
 
-Tiling: decode shapes are small (one token × a KV window), so kernels block
-over the only large axis (rows of Q / the cache) and keep the reduction
-resident in VMEM.
+Tiling: the three matmuls (``attention_qk``, ``attention_pv``,
+``decode_gemv``) run on the bit-sliced MXU kernel
+(``bitslice_matmul.wide_matmul``): the MXU has no int32 × int32 path, so
+their int32 operands split into int8 slices — as many as the static
+precision hints ask for, four without one — whose shifted products sum in
+int32 and wrap like the oracles.  ``softmax_fixedpoint`` blocks rows (a
+multiple of 8, rows zero-padded) and keeps each whole row in VMEM;
+``kv_append`` takes its selector as a (T, 1) column.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,15 +34,8 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import ref
 from repro.kernels.api import register_kernel
-from repro.kernels.ewise import _block_size
-
-
-def _int_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """a @ b with int32 accumulation (the MXU's widened integer path)."""
-    return jax.lax.dot_general(
-        a.astype(jnp.int32), b.astype(jnp.int32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+from repro.kernels.bitslice_matmul import wide_matmul
+from repro.kernels.tiling import fit_block, pad_to
 
 
 # ---------------------------------------------------------------------------
@@ -45,39 +43,23 @@ def _int_dot(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _qk_kernel(q_ref, kt_ref, o_ref):
-    o_ref[...] = _int_dot(q_ref[...], kt_ref[...])
-
-
 @register_kernel("attention_qk", oracle=ref.attention_qk_ref)
 def attention_qk(
     q: jnp.ndarray, k: jnp.ndarray, *,
     q_bits: Optional[int] = None, k_bits: Optional[int] = None,
     out_bits: Optional[int] = None,
-    block_m: int = 128, interpret: bool = False,
+    block: Tuple[int, int, int] = (256, 256, 256), interpret: bool = False,
 ) -> jnp.ndarray:
     """(M, D) query block × (T, D) key cache → (M, T) int32 scores q·Kᵀ.
 
-    ``q_bits``/``k_bits``/``out_bits`` are pimsab precision hints (see the
-    oracle's docstring for the ``out_bits`` overflow contract); the TPU path
-    ignores them.
+    ``q_bits``/``k_bits`` set the slice counts; ``out_bits`` is the
+    pimsab score-field hint (see the oracle's docstring for its overflow
+    contract).  ``block`` is the (M, T, D) matmul block.
     """
-    del q_bits, k_bits, out_bits
-    m, d = q.shape
-    t, d2 = k.shape
-    assert d == d2, (d, d2)
-    bm = _block_size(m, block_m)
-    return pl.pallas_call(
-        _qk_kernel,
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, d), lambda i: (i, 0)),
-            pl.BlockSpec((d, t), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, t), jnp.int32),
-        interpret=interpret,
-    )(q, k.T)
+    del out_bits
+    assert q.shape[1] == k.shape[1], (q.shape, k.shape)
+    return wide_matmul(q, k.T, x_bits=q_bits, w_bits=k_bits, block=block,
+                       interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -87,7 +69,7 @@ def attention_qk(
 
 def _softmax_kernel(x_ref, o_ref, *, sigma: int):
     f, kk, fi = ref.SOFTMAX_F, ref.SOFTMAX_K, ref.SOFTMAX_FI
-    x = x_ref[...].astype(jnp.int32)
+    x = x_ref[...]
     t = x - jnp.max(x, axis=-1, keepdims=True)
     tcl = jnp.maximum(t, -(1 << (f + sigma)))
     u = jnp.right_shift(tcl, sigma)
@@ -116,7 +98,8 @@ def softmax_fixedpoint(
 ) -> jnp.ndarray:
     """Bit-exact fixed-point row softmax of (R, T) integers with ``in_frac``
     fraction bits → int32 probabilities with ``SOFTMAX_F`` fraction bits
-    (identical recipe to the oracle / the pimsab machine, shift for shift)."""
+    (identical recipe to the oracle / the pimsab machine, shift for shift).
+    Rows are zero-padded to whole ``block_r``-row blocks."""
     del in_bits
     f, kk = ref.SOFTMAX_F, ref.SOFTMAX_K
     in_frac = int(in_frac)
@@ -125,16 +108,17 @@ def softmax_fixedpoint(
             f"softmax_fixedpoint needs in_frac >= {f - kk} (got {in_frac})"
         )
     r, t = x.shape
-    br = _block_size(r, block_r)
+    br, rp = fit_block(r, block_r, 8)
     kernel = functools.partial(_softmax_kernel, sigma=in_frac - f + kk)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        grid=(r // br,),
+        grid=(rp // br,),
         in_specs=[pl.BlockSpec((br, t), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, t), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((rp, t), jnp.int32),
         interpret=interpret,
-    )(x)
+    )(pad_to(x.astype(jnp.int32), (rp, t)))
+    return out[:r]
 
 
 # ---------------------------------------------------------------------------
@@ -142,36 +126,19 @@ def softmax_fixedpoint(
 # ---------------------------------------------------------------------------
 
 
-def _pv_kernel(p_ref, v_ref, o_ref, *, shift: int):
-    o_ref[...] = jnp.right_shift(_int_dot(p_ref[...], v_ref[...]), shift)
-
-
 @register_kernel("attention_pv", oracle=ref.attention_pv_ref)
 def attention_pv(
     p: jnp.ndarray, v: jnp.ndarray, *, shift: int = ref.SOFTMAX_F,
     p_bits: Optional[int] = None, v_bits: Optional[int] = None,
-    block_m: int = 128, interpret: bool = False,
+    block: Tuple[int, int, int] = (256, 256, 256), interpret: bool = False,
 ) -> jnp.ndarray:
     """(M, T) probabilities × (T, Dv) value cache → (M, Dv) int32, with the
     int32 accumulator arithmetically shifted right by ``shift`` (floor) —
-    renormalizing ``SOFTMAX_F``-fraction probabilities to the value scale."""
-    del p_bits, v_bits
-    m, t = p.shape
-    t2, dv = v.shape
-    assert t == t2, (t, t2)
-    bm = _block_size(m, block_m)
-    kernel = functools.partial(_pv_kernel, shift=int(shift))
-    return pl.pallas_call(
-        kernel,
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, t), lambda i: (i, 0)),
-            pl.BlockSpec((t, dv), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, dv), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, dv), jnp.int32),
-        interpret=interpret,
-    )(p, v)
+    renormalizing ``SOFTMAX_F``-fraction probabilities to the value scale.
+    ``p_bits``/``v_bits`` set the slice counts."""
+    assert p.shape[1] == v.shape[0], (p.shape, v.shape)
+    return wide_matmul(p, v, x_bits=p_bits, w_bits=v_bits, out_shift=int(shift),
+                       block=block, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -179,34 +146,20 @@ def attention_pv(
 # ---------------------------------------------------------------------------
 
 
-def _gemv_kernel(w_ref, x_ref, o_ref):
-    o_ref[...] = _int_dot(w_ref[...], x_ref[...])
-
-
 @register_kernel("decode_gemv", oracle=ref.decode_gemv_ref)
 def decode_gemv(
     w: jnp.ndarray, x: jnp.ndarray, *,
     w_bits: Optional[int] = None, x_bits: Optional[int] = None,
-    block_m: int = 128, interpret: bool = False,
+    block: Tuple[int, int, int] = (256, 256, 256), interpret: bool = False,
 ) -> jnp.ndarray:
     """(M, K) weights × (K,) activation → (M,) int32 single-token decode
     projection (the pimsab lowering rides the activation down the RF
-    constant path; here it is a width-1 MXU matmul)."""
-    del w_bits, x_bits
+    constant path; here it is a width-1 MXU matmul, the activation column
+    zero-padded to 128 lanes).  ``w_bits``/``x_bits`` set the slice counts."""
     m, k = w.shape
     assert x.shape == (k,), (x.shape, k)
-    bm = _block_size(m, block_m)
-    out = pl.pallas_call(
-        _gemv_kernel,
-        grid=(m // bm,),
-        in_specs=[
-            pl.BlockSpec((bm, k), lambda i: (i, 0)),
-            pl.BlockSpec((k, 1), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((bm, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, 1), jnp.int32),
-        interpret=interpret,
-    )(w, x.reshape(k, 1))
+    out = wide_matmul(w, x.reshape(k, 1), x_bits=w_bits, w_bits=x_bits,
+                      block=block, interpret=interpret)
     return out.reshape(m)
 
 
@@ -216,8 +169,7 @@ def decode_gemv(
 
 
 def _kv_append_kernel(c_ref, n_ref, s_ref, o_ref):
-    sel = (s_ref[...] != 0)[:, None]
-    o_ref[...] = jnp.where(sel, n_ref[...].astype(c_ref.dtype), c_ref[...])
+    o_ref[...] = jnp.where(s_ref[...] != 0, n_ref[...], c_ref[...])
 
 
 @register_kernel("kv_append", oracle=ref.kv_append_ref)
@@ -229,7 +181,8 @@ def kv_append(
     replaced by the (D,) ``new`` row (all-zero selector → no-op).  The
     pimsab lowering latches the selector into the PE mask and, as a
     ``ResidentState`` updater, performs the scatter in place on reserved
-    CRAM wordlines."""
+    CRAM wordlines.  Here one whole-array block, with the selector as a
+    (T, 1) int32 column."""
     t, d = cache.shape
     assert new.shape == (d,), (new.shape, d)
     assert onehot.shape == (t,), (onehot.shape, t)
@@ -238,9 +191,9 @@ def kv_append(
         in_specs=[
             pl.BlockSpec((t, d), lambda: (0, 0)),
             pl.BlockSpec((1, d), lambda: (0, 0)),
-            pl.BlockSpec((t,), lambda: (0,)),
+            pl.BlockSpec((t, 1), lambda: (0, 0)),
         ],
         out_specs=pl.BlockSpec((t, d), lambda: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), cache.dtype),
         interpret=interpret,
-    )(cache, new.reshape(1, d), onehot)
+    )(cache, new.astype(cache.dtype).reshape(1, d), onehot.astype(jnp.int32).reshape(t, 1))

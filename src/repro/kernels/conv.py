@@ -6,19 +6,27 @@ MXU/VPU primitives; on the pimsab backend the same registry names lower onto
 the paper's architecture (``repro.kernels.pimsab_backend``):
 
 * ``conv2d``      — im2col (the §V-A layout contract lives in
-  ``ref.im2col``) followed by a blocked MXU matmul; pimsab runs the identical
-  patch matrix through the ``mac`` gemm pipeline.
+  ``ref.im2col``) followed by the bit-sliced MXU matmul
+  (``bitslice_matmul.wide_matmul``); pimsab runs the identical patch matrix
+  through the ``mac`` gemm pipeline.
 * ``int_matmul``  — raw-integer (M, K) × (K, N) with int32 accumulation: the
   network-head matmul whose activations arrive as another kernel's integer
-  output (no slice stacks involved, unlike ``bitslice_matmul``).
+  output; the kernel slices them itself.
 * ``maxpool2d`` / ``avgpool2d`` / ``global_avgpool`` — window reductions over
   the ``ref.pool_patches`` window matrix; pimsab folds max via CmpGE +
   masked copy and average via the constant-operand MAC plus a shift-read
   divide.
 
-``x_bits`` / ``w_bits`` are *static precision hints* consumed only by the
-pimsab lowering (program mode cannot calibrate precision from values); the
-TPU kernels and oracles ignore them.
+``x_bits`` / ``w_bits`` are *static precision hints*: the pimsab lowering
+sizes its fields from them (program mode cannot calibrate precision from
+values), and the TPU matmuls take one int8 slice per 8 bits of them (four
+slices without a hint).  The oracles ignore them.
+
+Tiling: the matmuls follow ``bitslice_matmul`` (aligned blocks, operands
+zero-padded, e.g. the 1000-class head to 1024 columns).  The pools transpose
+the window matrix to (K, P), lay P out as rows × 128 lanes
+(``tiling.lane_rows``) and block the rows; the K window slabs of one block
+sit in VMEM together.
 """
 from __future__ import annotations
 
@@ -31,75 +39,33 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import ref
 from repro.kernels.api import register_kernel
-
-
-def _block_size(n: int, block: int) -> int:
-    """Largest divisor of n that is ≤ block (grids need exact tiling)."""
-    for bn in range(min(block, n), 0, -1):
-        if n % bn == 0:
-            return bn
-    return 1
-
-
-# ---------------------------------------------------------------------------
-# blocked 2-D matmul body (shared by conv2d and int_matmul)
-# ---------------------------------------------------------------------------
-
-
-def _dot_kernel(x_ref, w_ref, o_ref, *, acc_dtype):
-    o_ref[...] = jax.lax.dot_general(
-        x_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype,
-    )
-
-
-def _blocked_matmul(
-    x: jnp.ndarray, w: jnp.ndarray, block: Tuple[int, int], interpret: bool
-) -> jnp.ndarray:
-    """(M, K) @ (K, N), K unblocked (network shapes keep K modest), output
-    blocked (bm, bn) over the grid.  Integer inputs accumulate in int32."""
-    m, k = x.shape
-    k2, n = w.shape
-    assert k == k2, (k, k2)
-    acc = jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.float32
-    bm, bn = _block_size(m, block[0]), _block_size(n, block[1])
-    return pl.pallas_call(
-        functools.partial(_dot_kernel, acc_dtype=acc),
-        grid=(m // bm, n // bn),
-        in_specs=[
-            pl.BlockSpec((bm, k), lambda i, j: (i, 0)),
-            pl.BlockSpec((k, bn), lambda i, j: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), acc),
-        interpret=interpret,
-    )(x.astype(acc), w.astype(acc))
-
-
-# ---------------------------------------------------------------------------
-# pooling bodies: blocked over output elements, full window axis resident
-# ---------------------------------------------------------------------------
+from repro.kernels.bitslice_matmul import wide_matmul
+from repro.kernels.tiling import LANES, lane_rows, load32
 
 
 def _pool_max_kernel(p_ref, o_ref):
-    o_ref[...] = jnp.max(p_ref[...], axis=1)
+    o_ref[...] = jnp.max(load32(p_ref), axis=0).astype(o_ref.dtype)
 
 
 def _pool_sum_kernel(p_ref, o_ref, *, acc_dtype):
-    o_ref[...] = jnp.sum(p_ref[...].astype(acc_dtype), axis=1)
+    o_ref[...] = jnp.sum(p_ref[...].astype(acc_dtype), axis=0)
 
 
 def _blocked_pool(kernel, patches: jnp.ndarray, out_dtype, block: int, interpret: bool):
+    """Reduce the (P, K) window matrix over K.  The window axis leads, so
+    each grid step reduces K (block × 128) slabs elementwise on the VPU."""
     p, k = patches.shape
-    bp = _block_size(p, block)
-    return pl.pallas_call(
+    slabs, br = lane_rows(patches.T, block)          # (K, R, 128)
+    r = slabs.shape[1]
+    out = pl.pallas_call(
         kernel,
-        grid=(p // bp,),
-        in_specs=[pl.BlockSpec((bp, k), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((bp,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((p,), out_dtype),
+        grid=(r // br,),
+        in_specs=[pl.BlockSpec((k, br, LANES), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((r, LANES), out_dtype),
         interpret=interpret,
-    )(patches)
+    )(slabs)
+    return out.reshape(r * LANES)[:p]
 
 
 def _acc_dtype(x: jnp.ndarray):
@@ -120,23 +86,22 @@ def conv2d(
     padding: int = 0,
     x_bits: Optional[int] = None,
     w_bits: Optional[int] = None,
-    block: Tuple[int, int] = (256, 256),
+    block: Tuple[int, int, int] = (256, 256, 256),
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(N, C, H, W) × (OC, C, KH, KW) → (N, OC, OH, OW) via im2col + MXU.
 
     Integer inputs accumulate in int32 (wrapping, like the oracle); float
-    inputs in float32.  ``x_bits``/``w_bits`` are pimsab-only hints, ignored
-    here.
+    inputs in float32.  ``x_bits``/``w_bits`` set the slice counts.
     """
-    del x_bits, w_bits
     n, c, h, hw = x.shape
     oc, c2, kh, kw = w.shape
     assert c == c2, (c, c2)
     oh, ow = ref.conv2d_out_hw(h, hw, kh, kw, stride, padding)
     patches = ref.im2col(x, kh, kw, stride, padding)          # (N·OH·OW, C·KH·KW)
     wm = w.reshape(oc, c * kh * kw).transpose()               # (C·KH·KW, OC)
-    out = _blocked_matmul(patches, wm, block, interpret)
+    out = wide_matmul(patches, wm, x_bits=x_bits, w_bits=w_bits,
+                      block=block, interpret=interpret)
     return out.reshape(n, oh, ow, oc).transpose(0, 3, 1, 2)
 
 
@@ -147,12 +112,12 @@ def int_matmul(
     *,
     x_bits: Optional[int] = None,
     w_bits: Optional[int] = None,
-    block: Tuple[int, int] = (256, 256),
+    block: Tuple[int, int, int] = (256, 256, 256),
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(M, K) × (K, N) raw-integer matmul, int32 accumulation (wrapping)."""
-    del x_bits, w_bits
-    return _blocked_matmul(x.astype(jnp.int32), w.astype(jnp.int32), block, interpret)
+    return wide_matmul(x.astype(jnp.int32), w.astype(jnp.int32), x_bits=x_bits,
+                       w_bits=w_bits, block=block, interpret=interpret)
 
 
 @register_kernel("maxpool2d", oracle=ref.maxpool2d_ref)
@@ -161,7 +126,7 @@ def maxpool2d(
     *,
     window: int = 2,
     stride: Optional[int] = None,
-    block: int = 512,
+    block: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(N, C, H, W) → (N, C, OH, OW) window max (no padding)."""
@@ -178,7 +143,7 @@ def avgpool2d(
     x: jnp.ndarray,
     *,
     window: int = 2,
-    block: int = 512,
+    block: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(N, C, H, W) → (N, C, OH, OW) window average, stride == window.
@@ -199,7 +164,7 @@ def avgpool2d(
 
 @register_kernel("global_avgpool", oracle=ref.global_avgpool_ref)
 def global_avgpool(
-    x: jnp.ndarray, *, block: int = 512, interpret: bool = False
+    x: jnp.ndarray, *, block: int = 128, interpret: bool = False
 ) -> jnp.ndarray:
     """(N, C, H, W) → (N, C) spatial average (integer: floor-divide by H·W)."""
     n, c, h, w = x.shape

@@ -7,12 +7,11 @@ conformance suite and the architecture-simulator backend an elementwise
 lowering (`map_add` / `relu` in the tensor DSL) next to the MAC-shaped
 kernels.
 
-Tiling: operands are flattened and blocked 1-D; the grid streams blocks
-through VMEM.
+Tiling: operands are flattened, zero-padded and laid out as rows × 128 lanes
+(``tiling.lane_rows``; a 1-D block cannot match XLA's ``T(1024)`` layout of
+a 1-D array); the grid streams ``block``-row blocks through VMEM.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,39 +19,32 @@ from jax.experimental import pallas as pl
 
 from repro.kernels import ref
 from repro.kernels.api import register_kernel
+from repro.kernels.tiling import LANES, lane_rows, load32
 
 
 def _add_kernel(x_ref, y_ref, o_ref):
-    o_ref[...] = x_ref[...] + y_ref[...]
+    o_ref[...] = (load32(x_ref) + load32(y_ref)).astype(o_ref.dtype)
 
 
 def _relu_kernel(x_ref, o_ref):
-    x = x_ref[...]
-    o_ref[...] = jnp.maximum(x, jnp.zeros_like(x))
+    x = load32(x_ref)
+    o_ref[...] = jnp.maximum(x, jnp.zeros_like(x)).astype(o_ref.dtype)
 
 
-def _block_size(n: int, block: int) -> int:
-    """Largest divisor of n that is ≤ block (grids need exact tiling)."""
-    for bn in range(min(block, n), 0, -1):
-        if n % bn == 0:
-            return bn
-    return 1
-
-
-def _blocked_1d(kernel, args, block: int, interpret: bool) -> jnp.ndarray:
+def _blocked_rows(kernel, args, block: int, interpret: bool) -> jnp.ndarray:
     x = args[0]
-    n = x.size
-    flat = [a.reshape(n) for a in args]
-    bn = _block_size(n, block)
+    tiles, brs = zip(*(lane_rows(a.reshape(x.size), block) for a in args))
+    r, br = tiles[0].shape[0], brs[0]
+    spec = pl.BlockSpec((br, LANES), lambda i: (i, 0))
     out = pl.pallas_call(
         kernel,
-        grid=(n // bn,),
-        in_specs=[pl.BlockSpec((bn,), lambda i: (i,)) for _ in flat],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
+        grid=(r // br,),
+        in_specs=[spec] * len(tiles),
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((r, LANES), x.dtype),
         interpret=interpret,
-    )(*flat)
-    return out.reshape(x.shape)
+    )(*tiles)
+    return out.reshape(r * LANES)[: x.size].reshape(x.shape)
 
 
 @register_kernel("ewise_add", oracle=ref.ewise_add_ref)
@@ -61,10 +53,10 @@ def ewise_add(
 ) -> jnp.ndarray:
     """x + y, any matching shapes/dtype."""
     assert x.shape == y.shape, (x.shape, y.shape)
-    return _blocked_1d(_add_kernel, (x, y.astype(x.dtype)), block, interpret)
+    return _blocked_rows(_add_kernel, (x, y.astype(x.dtype)), block, interpret)
 
 
 @register_kernel("relu", oracle=ref.relu_ref)
 def relu(x: jnp.ndarray, *, block: int = 512, interpret: bool = False) -> jnp.ndarray:
     """max(x, 0)."""
-    return _blocked_1d(_relu_kernel, (x,), block, interpret)
+    return _blocked_rows(_relu_kernel, (x,), block, interpret)

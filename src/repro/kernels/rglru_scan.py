@@ -7,7 +7,11 @@ once — O(T·W) — carrying h in a VMEM scratch across sequential grid steps
 (TPU grid iteration order is sequential, last axis fastest, which Pallas
 guarantees; interpret mode preserves it).
 
-Grid: (B, W/bw, T/bt); h-scratch (bw,) persists across the T axis.
+Grid: (B, W/bw, T/bt).  Every ref is 2-D in its last two axes: h0 is viewed
+as (B, 1, W) and the h-scratch is (1, bw), so each block's last two
+dimensions are (8, 128)-aligned or whole; step i reads and writes one
+(1, bw) row.  T and W are zero-padded to whole blocks (padded steps and
+lanes only produce outputs that are sliced away).
 """
 from __future__ import annotations
 
@@ -20,6 +24,7 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels import ref
 from repro.kernels.api import register_kernel
+from repro.kernels.tiling import LANES, fit_block, pad_to
 
 
 def _kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, bt: int):
@@ -29,18 +34,13 @@ def _kernel(a_ref, b_ref, h0_ref, o_ref, h_ref, *, bt: int):
     def _init():
         h_ref[...] = h0_ref[0]
 
-    h = h_ref[...]
-    out = jnp.zeros_like(b_ref[0])
+    def body(i, h):
+        row = pl.ds(i, 1)
+        h = a_ref[0, row, :] * h + b_ref[0, row, :]
+        o_ref[0, row, :] = h
+        return h
 
-    def body(i, carry):
-        h, out = carry
-        h = a_ref[0, i] * h + b_ref[0, i]
-        out = jax.lax.dynamic_update_index_in_dim(out, h, i, 0)
-        return h, out
-
-    h, out = jax.lax.fori_loop(0, bt, body, (h, out))
-    o_ref[0] = out
-    h_ref[...] = h
+    h_ref[...] = jax.lax.fori_loop(0, bt, body, h_ref[...])
 
 
 @register_kernel("rglru_scan", oracle=ref.rglru_scan_ref)
@@ -55,19 +55,18 @@ def rglru_scan(
 ) -> jnp.ndarray:
     """a, b: (B, T, W) fp32; h0: (B, W).  Returns hs: (B, T, W)."""
     bsz, t, w = a.shape
-    bt, bw = min(block_t, t), min(block_w, w)
-    assert t % bt == 0 and w % bw == 0, (t, w, bt, bw)
-    grid = (bsz, w // bw, t // bt)  # T innermost: h carries across chunks
-    return pl.pallas_call(
+    bt, tp = fit_block(t, block_t, 8)
+    bw, wp = fit_block(w, block_w, LANES)
+    grid = (bsz, wp // bw, tp // bt)  # T innermost: h carries across chunks
+    seq = pl.BlockSpec((1, bt, bw), lambda i, j, k: (i, k, j))
+    out = pl.pallas_call(
         functools.partial(_kernel, bt=bt),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bt, bw), lambda i, j, k: (i, k, j)),
-            pl.BlockSpec((1, bt, bw), lambda i, j, k: (i, k, j)),
-            pl.BlockSpec((1, bw), lambda i, j, k: (i, j)),
-        ],
-        out_specs=pl.BlockSpec((1, bt, bw), lambda i, j, k: (i, k, j)),
-        out_shape=jax.ShapeDtypeStruct((bsz, t, w), a.dtype),
-        scratch_shapes=[pltpu.VMEM((bw,), a.dtype)],
+        in_specs=[seq, seq, pl.BlockSpec((1, 1, bw), lambda i, j, k: (i, 0, j))],
+        out_specs=seq,
+        out_shape=jax.ShapeDtypeStruct((bsz, tp, wp), a.dtype),
+        scratch_shapes=[pltpu.VMEM((1, bw), a.dtype)],
         interpret=interpret,
-    )(a, b, h0)
+    )(pad_to(a, (bsz, tp, wp)), pad_to(b, (bsz, tp, wp)),
+      pad_to(h0.reshape(bsz, 1, w), (bsz, 1, wp)))
+    return out[:, :t, :w]

@@ -13,6 +13,7 @@ import jax
 
 from repro.configs import get_config, reduced_config
 from repro.data.pipeline import DataConfig
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models.runtime import RunFlags
 from repro.train.trainer import TrainLoopConfig, train
 
@@ -30,6 +31,7 @@ def main() -> None:
     ap.add_argument("--no-resume", action="store_true")
     args = ap.parse_args()
 
+    setup_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)

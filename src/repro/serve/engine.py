@@ -127,17 +127,21 @@ class ServeEngine:
         self.params = maybe_quantize_tree(params, cfg) if flags.quant_serve else params
         # compile-once: identical engine signatures share the jitted steps
         # (jax re-traces a fresh lambda per jit object — caching the jitted
-        # callable, not just the XLA executable, avoids that too)
-        self._prefill = cached_executable(
+        # callable, not just the XLA executable, avoids that too).
+        # ``prefill_step(params, batch)`` and ``decode_step(params, cache,
+        # tokens)`` return ``(cache, logits)``; the logits span the padded
+        # vocabulary (``cfg.padded_vocab()``)
+        self.prefill_step = cached_executable(
             ("serve_step", "prefill", repr(cfg), repr(flags), backend, max_len),
             lambda: jax.jit(make_prefill_step(cfg, flags, max_len=max_len, backend=backend)),
         )
-        self._decode = cached_executable(
+        self.decode_step = cached_executable(
             ("serve_step", "decode", repr(cfg), repr(flags), backend),
             lambda: jax.jit(make_decode_step(cfg, flags, backend=backend)),
         )
 
-    def run(self, requests: List[Request]) -> List[Request]:
+    def pack(self, requests: List[Request]) -> Dict[str, jnp.ndarray]:
+        """The prefill batch: prompts left-padded to one length (at least 8)."""
         b = len(requests)
         s = max(len(r.prompt) for r in requests)
         s = max(s, 8)
@@ -149,9 +153,16 @@ class ServeEngine:
             batch["patch_embeds"] = jnp.zeros((b, self.cfg.n_patches, self.cfg.d_model), jnp.dtype(self.cfg.dtype))
         if self.cfg.is_encdec:
             batch["enc_embeds"] = jnp.zeros((b, self.cfg.enc_seq_len, self.cfg.d_model), jnp.dtype(self.cfg.dtype))
-        cache, logits = self._prefill(self.params, batch)
+        return batch
+
+    def _greedy(self, logits) -> np.ndarray:
+        # the embedding is padded past the vocabulary; those ids are no tokens
+        return np.array(jnp.argmax(logits[:, : self.cfg.vocab_size], axis=-1), np.int32)
+
+    def run(self, requests: List[Request]) -> List[Request]:
+        cache, logits = self.prefill_step(self.params, self.pack(requests))
         steps = max(r.max_new_tokens for r in requests)
-        next_tok = np.array(jnp.argmax(logits, axis=-1), np.int32)
+        next_tok = self._greedy(logits)
         for _ in range(steps):
             for i, r in enumerate(requests):
                 if not r.done:
@@ -165,6 +176,6 @@ class ServeEngine:
                     next_tok[i] = 0
             if all(r.done for r in requests):
                 break
-            cache, logits = self._decode(self.params, cache, jnp.asarray(next_tok)[:, None])
-            next_tok = np.array(jnp.argmax(logits, axis=-1), np.int32)
+            cache, logits = self.decode_step(self.params, cache, jnp.asarray(next_tok)[:, None])
+            next_tok = self._greedy(logits)
         return requests

@@ -82,3 +82,110 @@ def test_quantized_matmul_end_to_end_error_bound():
     )
     rel = float(jnp.abs(out - x @ w).max() / jnp.abs(x @ w).max())
     assert rel < 0.05, rel
+
+
+# ---------------------------------------------------------------------------
+# shapes and values the TPU forces: padded blocks, lane-tiled 1-D ops, the
+# int32 matmuls on int8 slices (interpret mode runs the same kernel bodies
+# and block specs the chip compiles)
+# ---------------------------------------------------------------------------
+
+_I32 = (np.iinfo(np.int32).min, np.iinfo(np.int32).max)
+
+
+def _near_wrap(shape, seed):
+    """int32 values within 2^12 of either end of the range."""
+    rng = np.random.default_rng(seed)
+    off = rng.integers(0, 1 << 12, shape)
+    return jnp.asarray(np.where(rng.random(shape) < 0.5, _I32[0] + off, _I32[1] - off),
+                       jnp.int32)
+
+
+def _full(shape, seed, lo=_I32[0], hi=_I32[1]):
+    return jnp.asarray(np.random.default_rng(seed).integers(lo, hi, shape), jnp.int32)
+
+
+_AWKWARD = {
+    # 1000-class head: N padded to 1024 columns
+    "int_matmul_head_n1000": lambda: (
+        "int_matmul", (_full((24, 512), 1, -(1 << 11), 1 << 11), _full((512, 1000), 2, -3, 4)),
+        {"x_bits": 12, "w_bits": 3}),
+    "int_matmul_near_wrap": lambda: (
+        "int_matmul", (_near_wrap((40, 300), 3), _near_wrap((300, 130), 4)), {}),
+    "conv2d_near_wrap": lambda: (
+        "conv2d", (_near_wrap((2, 5, 9, 9), 5), _near_wrap((7, 5, 3, 3), 6)),
+        {"stride": 2, "padding": 1}),
+    # a hinted operand at the top of its range (32767 needs a third slice)
+    "conv2d_hint_edge": lambda: (
+        "conv2d", (jnp.full((1, 2, 6, 6), 32767, jnp.int32), _full((3, 2, 3, 3), 7, -3, 4)),
+        {"stride": 1, "padding": 1, "x_bits": 16, "w_bits": 3}),
+    "attention_qk_near_wrap": lambda: (
+        "attention_qk", (_near_wrap((5, 64), 8), _near_wrap((37, 64), 9)), {}),
+    "attention_pv_full_range": lambda: (
+        "attention_pv", (_full((5, 37), 10, 0, 65), _full((37, 64), 11)), {}),
+    "decode_gemv_near_wrap": lambda: (
+        "decode_gemv", (_near_wrap((1000, 96), 12), _near_wrap((96,), 13)), {}),
+    "ewise_add_945_wraps": lambda: (
+        "ewise_add", (_near_wrap((3, 5, 7, 9), 14), _near_wrap((3, 5, 7, 9), 15)), {}),
+    "relu_945_int8": lambda: (
+        "relu", (_full((3, 5, 7, 9), 16, -128, 128).astype(jnp.int8),), {}),
+    "maxpool2d_150_windows": lambda: (
+        "maxpool2d", (_near_wrap((2, 3, 10, 10), 17),), {"window": 2}),
+    "maxpool2d_overlapping": lambda: (
+        "maxpool2d", (_full((1, 3, 11, 11), 18),), {"window": 3, "stride": 2}),
+    "avgpool2d_150_windows": lambda: (
+        "avgpool2d", (_full((2, 3, 10, 10), 19, -(1 << 28), 1 << 28),), {"window": 2}),
+    "global_avgpool_15_rows": lambda: (
+        "global_avgpool", (_full((3, 5, 4, 4), 20, -(1 << 27), 1 << 27),), {}),
+    "softmax_37_rows": lambda: (
+        "softmax_fixedpoint", (_full((37, 19), 21, -3000, 3000),), {"in_frac": 7}),
+    "kv_append_13_rows": lambda: (
+        "kv_append", (_full((13, 20), 22), _full((20,), 23),
+                      jnp.zeros(13, jnp.int32).at[12].set(1)), {}),
+    "htree_reduce_d100_bf16": lambda: (
+        "htree_reduce",
+        (jax.random.normal(jax.random.key(24), (16, 100)).astype(jnp.bfloat16),), {}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_AWKWARD))
+def test_kernel_awkward_shape_bit_exact(case):
+    name, args, kwargs = _AWKWARD[case]()
+    with use_backend("xla"):
+        want = api.dispatch(name, *args, **kwargs)
+    with use_backend("interpret"):
+        got = api.dispatch(name, *args, **kwargs)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+def test_rglru_scan_padded_blocks():
+    """T and W that are no multiples of the (8, 128) tile."""
+    ks = jax.random.split(jax.random.key(0), 3)
+    a = jax.nn.sigmoid(jax.random.normal(ks[0], (2, 37, 200)))
+    b = jax.random.normal(ks[1], (2, 37, 200))
+    h0 = jax.random.normal(ks[2], (2, 200))
+    with use_backend("interpret"):
+        got = api.rglru_scan(a, b, h0, block_t=16, block_w=128)
+    np.testing.assert_allclose(np.asarray(ref.rglru_scan_ref(a, b, h0)), np.asarray(got),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("bits,n", [(None, 4), (1, 1), (8, 1), (9, 2), (15, 2),
+                                    (16, 3), (24, 4), (32, 4)])
+def test_int_slices_are_exact_within_hint(bits, n):
+    from repro.kernels.bitslice_matmul import int_slices, slices_for_bits
+
+    assert slices_for_bits(bits) == n
+    b = 32 if bits is None else bits
+    lo, hi = -(1 << (b - 1)), (1 << (b - 1)) - 1
+    edge = np.array([lo, hi, 0, -1, 1, lo + 1, hi - 1], np.int64)
+    rnd = np.random.default_rng(b).integers(lo, hi + 1, 200)
+    x = jnp.asarray(np.concatenate([edge, rnd]).astype(np.int32))
+    s = int_slices(x, n)
+    assert s.dtype == jnp.int8 and s.shape == (n, x.size)
+    recon = sum(np.asarray(s[i], np.int64) << (8 * i) for i in range(n))
+    if n < 4:
+        np.testing.assert_array_equal(recon, np.asarray(x, np.int64))
+    else:  # all four slices: exact modulo 2^32
+        np.testing.assert_array_equal(recon % (1 << 32), np.asarray(x, np.int64) % (1 << 32))

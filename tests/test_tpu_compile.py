@@ -1,0 +1,102 @@
+"""Compile rehearsals for one TPU v5e chip, without the chip.
+
+The TPU compiler compiles for a described (not attached) ``v5e:2x2``
+topology; each program here is compiled for its first chip, so Mosaic's
+block-shape, layout and lowering rules are checked on every test run — the
+rules interpret mode does not apply.  Shapes are those ``chip_smoke.py``
+runs on the chip.  Nothing executes: a compile that passes is not a chip
+run.
+
+The topology is described inside a module fixture (never at import: only
+one process at a time may load the TPU library, and every pytest worker
+imports this file), and the persistent compilation cache is off around the
+compiles (an entry written without a chip cannot be read back).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+import chip_smoke
+from repro.kernels import api
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", enabled)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def cases():
+    return {c.name: c for c in chip_smoke.kernel_cases()}
+
+
+def _struct(x, sharding):
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
+def _compile_pallas(fn, *args):
+    with api.use_backend("pallas"):
+        return jax.jit(fn).lower(*args).compile()
+
+
+@pytest.mark.parametrize("name", sorted(api.registered_kernels()))
+def test_registry_kernel_compiles_for_v5e(name, cases, one_chip):
+    case = cases[name]
+    compiled = _compile_pallas(
+        lambda *a: api.dispatch(name, *a, **case.kwargs),
+        *(_struct(a, one_chip) for a in case.args),
+    )
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_resnet18_program_compiles_for_v5e(one_chip):
+    from repro.models import resnet
+
+    cfg = resnet.RESNET18
+    params, x = jax.tree_util.tree_map(
+        lambda a: _struct(a, one_chip),
+        jax.eval_shape(lambda: (resnet.init_params(cfg),
+                                resnet.make_input(cfg, batch=chip_smoke.RESNET_BATCH))),
+    )
+    traced = api.trace(lambda p, x: resnet.forward(cfg, p, x), name="resnet18")
+    with api.use_backend("pallas"):
+        ex = api.compile(traced.trace(params, x))
+    text = jax.jit(lambda p, x: ex(p, x)).lower(params, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= len(resnet.layer_names(cfg))
+
+
+def test_qwen2_decode_step_compiles_for_v5e(one_chip):
+    from repro.configs import get_config
+    from repro.models.runtime import RunFlags
+    from repro.models.transformer import cache_shape
+    from repro.serve.engine import make_decode_step, serve_params_shape
+
+    cfg = get_config(chip_smoke.SERVE_ARCH)
+    flags = RunFlags(attn_chunk=64, flash_threshold=256, quant_serve=True)
+    batch, max_len = chip_smoke.SERVE_REQUESTS, chip_smoke.PROMPT_LEN + chip_smoke.NEW_TOKENS
+    params, cache = jax.tree_util.tree_map(
+        lambda a: _struct(a, one_chip),
+        (serve_params_shape(cfg, flags), cache_shape(cfg, batch, max_len, flags)),
+    )
+    tokens = jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip)
+    step = make_decode_step(cfg, flags, backend="pallas")
+    compiled = jax.jit(step).lower(params, cache, tokens).compile()
+    assert compiled.memory_analysis() is not None
