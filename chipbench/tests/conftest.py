@@ -1,0 +1,11 @@
+"""The benchmark's own CPU tests (kept out of the repository's tier-1 run):
+
+    JAX_PLATFORMS=cpu python -m pytest -q chipbench/tests
+"""
+import os
+import sys
+from pathlib import Path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = Path(__file__).resolve().parents[2]
+sys.path[:0] = [str(ROOT), str(ROOT / "src")]
