@@ -2,17 +2,15 @@
 
 The kernel list is enumerated from the backend registry
 (``repro.kernels.api.registered_kernels``) — not hand-maintained — so a new
-``@register_kernel`` automatically joins the bench.  Each kernel runs its
-oracle under ``use_backend("xla")`` (jit-compiled, what the CPU container can
-execute; the TPU target swaps the context to "pallas" with no other change)
-and is cross-checked once against interpret mode on a reduced shape.
+``@register_kernel`` automatically joins the bench.  Each kernel's Pallas
+body is cross-checked once, in interpret mode, against its oracle on a
+reduced shape (the kernels' speed on the chip is measured by ``chipbench/``).
 
-Alongside wall-clock, every kernel also runs once under
-``use_backend("pimsab")`` on a reduced shape: the call lowers through the
-tensor DSL → §V compiler → ISA, executes bit-exactly on the functional
-simulator, and attaches *modeled* full-chip cycles/energy via
-``api.last_sim_report()`` — so ``BENCH_kernels.json`` tracks the architecture
-model's trajectory next to the host numbers.
+Every kernel also runs once under ``use_backend("pimsab")`` on a reduced
+shape: the call lowers through the tensor DSL → §V compiler → ISA, executes
+bit-exactly on the functional simulator, and attaches *modeled* full-chip
+cycles/energy via ``api.last_sim_report()`` — so ``BENCH_kernels.json``
+tracks the architecture model's trajectory.
 
 A **program-mode** section runs the `matmul → ewise_add → relu` chain through
 ``api.trace``/``api.compile`` on the pimsab backend and records the
@@ -84,14 +82,14 @@ BENCH_TUNE = api.TuneConfig(budget=96, beam=4, seed=0)
 def _tuning_ctx(tune: Optional[api.TuneConfig]):
     return api.tuning(tune) if tune is not None else contextlib.nullcontext()
 
-# Bench operand builders per registered kernel: (bench shape, reduced
-# validation shape).  A kernel registered without an entry here still fails
-# loudly in run() — coverage is enforced by the registry, not this dict.
+# Validation operand builders per registered kernel (reduced shapes).  A
+# kernel registered without an entry in _cases() still fails loudly in run()
+# — coverage is enforced by the registry, not this dict.
 _SEED = 0
 
 
 def _img(shape, lo=-100, hi=100, seed=0):
-    """Random int32 tensor for the conv/pool/int-matmul bench cases."""
+    """Random int32 tensor for the conv/pool/int-matmul cases."""
     rng = np.random.default_rng(seed)
     return jnp.asarray(rng.integers(lo, hi, shape), jnp.int32)
 
@@ -120,150 +118,67 @@ def _bitslice_args(m, n, k, xb, wb):
     )
 
 
-def _cases() -> Dict[str, Dict[str, Callable]]:
+def _cases() -> Dict[str, Callable[[], bool]]:
+    """Per registered kernel: a check that interpret mode matches the oracle
+    on a reduced shape."""
     return {
-        "bitslice_matmul": {
-            "bench": lambda: _bench_call(api.matmul, *_bitslice_args(512, 512, 512, 8, 8)),
-            "validate": lambda: _validate_matmul(128, 128, 128, 8, 16),
-        },
-        "htree_reduce": {
-            "bench": lambda: _bench_call(
-                api.htree_reduce,
-                jax.random.normal(jax.random.key(_SEED), (256, 2048), jnp.float32),
-            ),
-            "validate": lambda: _validate_unary(
-                api.htree_reduce, ref.htree_reduce_ref,
-                jax.random.normal(jax.random.key(_SEED), (16, 512), jnp.float32),
-            ),
-        },
-        "rglru_scan": {
-            "bench": lambda: _bench_call(
-                api.rglru_scan,
-                jax.nn.sigmoid(jax.random.normal(jax.random.key(1), (2, 512, 1024))),
-                jax.random.normal(jax.random.key(2), (2, 512, 1024)),
-                jax.random.normal(jax.random.key(3), (2, 1024)),
-            ),
-            "validate": lambda: _validate_rglru(),
-        },
-        "ewise_add": {
-            "bench": lambda: _bench_call(
-                api.ewise_add,
-                jax.random.normal(jax.random.key(4), (1024, 1024), jnp.float32),
-                jax.random.normal(jax.random.key(5), (1024, 1024), jnp.float32),
-            ),
-            "validate": lambda: _validate_unary(
-                lambda x: api.ewise_add(x, x), lambda x: x + x,
-                jax.random.normal(jax.random.key(6), (64, 128), jnp.float32),
-            ),
-        },
-        "relu": {
-            "bench": lambda: _bench_call(
-                api.relu, jax.random.normal(jax.random.key(7), (1024, 1024), jnp.float32),
-            ),
-            "validate": lambda: _validate_unary(
-                api.relu, ref.relu_ref,
-                jax.random.normal(jax.random.key(8), (64, 128), jnp.float32),
-            ),
-        },
-        "conv2d": {
-            "bench": lambda: _bench_call(
-                lambda x, w: api.conv2d(x, w, stride=1, padding=1),
-                _img((8, 32, 32, 32), seed=9), _wconv((32, 32, 3, 3), seed=10),
-            ),
-            "validate": lambda: _validate_binary(
-                lambda x, w: api.conv2d(x, w, stride=1, padding=1),
-                lambda x, w: ref.conv2d_ref(x, w, stride=1, padding=1),
-                _img((1, 4, 8, 8), seed=11), _wconv((4, 4, 3, 3), seed=12),
-            ),
-        },
-        "int_matmul": {
-            "bench": lambda: _bench_call(
-                api.int_matmul, _img((512, 512), seed=13), _img((512, 512), seed=14),
-            ),
-            "validate": lambda: _validate_binary(
-                api.int_matmul, ref.int_matmul_ref,
-                _img((32, 64), seed=15), _img((64, 16), seed=16),
-            ),
-        },
-        "maxpool2d": {
-            "bench": lambda: _bench_call(
-                lambda x: api.maxpool2d(x, window=2), _img((8, 32, 64, 64), seed=17),
-            ),
-            "validate": lambda: _validate_unary(
-                lambda x: api.maxpool2d(x, window=2),
-                lambda x: ref.maxpool2d_ref(x, window=2),
-                _img((2, 4, 16, 16), seed=18),
-            ),
-        },
-        "avgpool2d": {
-            "bench": lambda: _bench_call(
-                lambda x: api.avgpool2d(x, window=2), _img((8, 32, 64, 64), seed=19),
-            ),
-            "validate": lambda: _validate_unary(
-                lambda x: api.avgpool2d(x, window=2),
-                lambda x: ref.avgpool2d_ref(x, window=2),
-                _img((2, 4, 16, 16), seed=20),
-            ),
-        },
-        "global_avgpool": {
-            "bench": lambda: _bench_call(
-                api.global_avgpool, _img((8, 256, 32, 32), seed=21),
-            ),
-            "validate": lambda: _validate_unary(
-                api.global_avgpool, ref.global_avgpool_ref,
-                _img((2, 8, 16, 16), seed=22),
-            ),
-        },
+        "bitslice_matmul": lambda: _validate_matmul(128, 128, 128, 8, 16),
+        "htree_reduce": lambda: _validate_unary(
+            api.htree_reduce, ref.htree_reduce_ref,
+            jax.random.normal(jax.random.key(_SEED), (16, 512), jnp.float32),
+        ),
+        "rglru_scan": lambda: _validate_rglru(),
+        "ewise_add": lambda: _validate_unary(
+            lambda x: api.ewise_add(x, x), lambda x: x + x,
+            jax.random.normal(jax.random.key(6), (64, 128), jnp.float32),
+        ),
+        "relu": lambda: _validate_unary(
+            api.relu, ref.relu_ref,
+            jax.random.normal(jax.random.key(8), (64, 128), jnp.float32),
+        ),
+        "conv2d": lambda: _validate_binary(
+            lambda x, w: api.conv2d(x, w, stride=1, padding=1),
+            lambda x, w: ref.conv2d_ref(x, w, stride=1, padding=1),
+            _img((1, 4, 8, 8), seed=11), _wconv((4, 4, 3, 3), seed=12),
+        ),
+        "int_matmul": lambda: _validate_binary(
+            api.int_matmul, ref.int_matmul_ref,
+            _img((32, 64), seed=15), _img((64, 16), seed=16),
+        ),
+        "maxpool2d": lambda: _validate_unary(
+            lambda x: api.maxpool2d(x, window=2),
+            lambda x: ref.maxpool2d_ref(x, window=2),
+            _img((2, 4, 16, 16), seed=18),
+        ),
+        "avgpool2d": lambda: _validate_unary(
+            lambda x: api.avgpool2d(x, window=2),
+            lambda x: ref.avgpool2d_ref(x, window=2),
+            _img((2, 4, 16, 16), seed=20),
+        ),
+        "global_avgpool": lambda: _validate_unary(
+            api.global_avgpool, ref.global_avgpool_ref,
+            _img((2, 8, 16, 16), seed=22),
+        ),
         # serving kernels — quantized single-head attention decode (see
         # docs/serving.md for the precision envelopes the shapes respect)
-        "attention_qk": {
-            "bench": lambda: _bench_call(
-                api.attention_qk, _img((64, 128), -7, 8, seed=40),
-                _img((512, 128), -15, 16, seed=41),
-            ),
-            "validate": lambda: _validate_binary(
-                api.attention_qk, ref.attention_qk_ref,
-                _img((4, 16), -7, 8, seed=42), _img((8, 16), -15, 16, seed=43),
-            ),
-        },
-        "softmax_fixedpoint": {
-            "bench": lambda: _bench_call(
-                lambda x: api.softmax_fixedpoint(x, in_frac=7),
-                _img((256, 512), -400, 400, seed=44),
-            ),
-            "validate": lambda: _validate_unary(
-                lambda x: api.softmax_fixedpoint(x, in_frac=7),
-                lambda x: ref.softmax_fixedpoint_ref(x, in_frac=7),
-                _img((8, 16), -400, 400, seed=45),
-            ),
-        },
-        "attention_pv": {
-            "bench": lambda: _bench_call(
-                api.attention_pv, _img((64, 512), 0, 65, seed=46),
-                _img((512, 128), seed=47),
-            ),
-            "validate": lambda: _validate_binary(
-                api.attention_pv, ref.attention_pv_ref,
-                _img((4, 8), 0, 65, seed=48), _img((8, 16), seed=49),
-            ),
-        },
-        "decode_gemv": {
-            "bench": lambda: _bench_call(
-                api.decode_gemv, _img((512, 512), -50, 50, seed=50),
-                _img((512,), -50, 50, seed=51),
-            ),
-            "validate": lambda: _validate_binary(
-                api.decode_gemv, ref.decode_gemv_ref,
-                _img((16, 32), -50, 50, seed=52), _img((32,), -50, 50, seed=53),
-            ),
-        },
-        "kv_append": {
-            "bench": lambda: _bench_call(
-                api.kv_append, _img((512, 128), seed=54), _img((128,), seed=55),
-                jnp.zeros(512, jnp.int8).at[17].set(1),
-            ),
-            "validate": lambda: _validate_kv_append(),
-        },
+        "attention_qk": lambda: _validate_binary(
+            api.attention_qk, ref.attention_qk_ref,
+            _img((4, 16), -7, 8, seed=42), _img((8, 16), -15, 16, seed=43),
+        ),
+        "softmax_fixedpoint": lambda: _validate_unary(
+            lambda x: api.softmax_fixedpoint(x, in_frac=7),
+            lambda x: ref.softmax_fixedpoint_ref(x, in_frac=7),
+            _img((8, 16), -400, 400, seed=45),
+        ),
+        "attention_pv": lambda: _validate_binary(
+            api.attention_pv, ref.attention_pv_ref,
+            _img((4, 8), 0, 65, seed=48), _img((8, 16), seed=49),
+        ),
+        "decode_gemv": lambda: _validate_binary(
+            api.decode_gemv, ref.decode_gemv_ref,
+            _img((16, 32), -50, 50, seed=52), _img((32,), -50, 50, seed=53),
+        ),
+        "kv_append": lambda: _validate_kv_append(),
     }
 
 
@@ -401,19 +316,6 @@ def _pimsab_cases() -> Dict[str, Callable]:
     }
 
 
-def _bench_call(fn, *args, iters: int = 5) -> float:
-    """Median wall-time (us) of the jitted call under the xla backend."""
-    with api.use_backend("xla"):
-        jitted = jax.jit(lambda *a: fn(*a))
-        jax.block_until_ready(jitted(*args))  # compile outside the timing
-        times = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            jax.block_until_ready(jitted(*args))
-            times.append((time.perf_counter() - t0) * 1e6)
-    return float(np.median(times))
-
-
 def _validate_matmul(m, n, k, xb, wb) -> bool:
     x, w = _bitslice_args(m, n, k, xb, wb)
     with api.use_backend("xla"):
@@ -455,15 +357,10 @@ def run(tune: Optional[api.TuneConfig] = BENCH_TUNE) -> List[Dict]:
         case = cases.get(name)
         if case is None:
             raise KeyError(
-                f"kernel {name!r} is registered but has no bench case — "
+                f"kernel {name!r} is registered but has no validation case — "
                 "add one to benchmarks/kernels_bench.py"
             )
-        row = {
-            "kernel": name,
-            "backend": "xla",
-            "us_per_call": round(case["bench"](), 3),
-            "interpret_matches_oracle": case["validate"](),
-        }
+        row = {"kernel": name, "interpret_matches_oracle": case()}
         sim_case = sim_cases.get(name)
         if sim_case is None:
             raise KeyError(

@@ -511,7 +511,9 @@ def dispatch(name: str, *args, pallas_kwargs: Optional[Dict[str, Any]] = None, *
     and other tiling knobs the oracle has no business seeing) only the
     Pallas call.  This is the single backend branch — the public wrappers
     below all go through it.  Inside :func:`trace` the call is recorded into
-    the Program under construction instead of executing.
+    the Program under construction instead of executing.  The call runs
+    under ``jax.named_scope(name)``, so the device ops it emits carry the
+    kernel's name in a profiler trace.
     """
     from repro.kernels import program as _program
 
@@ -520,20 +522,21 @@ def dispatch(name: str, *args, pallas_kwargs: Optional[Dict[str, Any]] = None, *
         return ctx.record(name, args, kwargs, pallas_kwargs)
     k = get_kernel(name)
     backend = current_backend()
-    if backend == "xla":
-        return k.oracle(*args, **kwargs)
-    if backend == "pimsab":
-        if k.pimsab is None:
-            raise NotImplementedError(
-                f"kernel {name!r} has no pimsab lowering "
-                "(register one with api.register_pimsab_impl)"
-            )
-        _require_concrete_operands(name, args)
-        # tiling knobs in pallas_kwargs are TPU-specific; the DSL compiler
-        # chooses its own distribution (§V-B)
-        return k.pimsab(*args, **kwargs)
-    kw = dict(kwargs, **(pallas_kwargs or {}))
-    return k.pallas(*args, interpret=(backend == "interpret"), **kw)
+    with jax.named_scope(name):
+        if backend == "xla":
+            return k.oracle(*args, **kwargs)
+        if backend == "pimsab":
+            if k.pimsab is None:
+                raise NotImplementedError(
+                    f"kernel {name!r} has no pimsab lowering "
+                    "(register one with api.register_pimsab_impl)"
+                )
+            _require_concrete_operands(name, args)
+            # tiling knobs in pallas_kwargs are TPU-specific; the DSL compiler
+            # chooses its own distribution (§V-B)
+            return k.pimsab(*args, **kwargs)
+        kw = dict(kwargs, **(pallas_kwargs or {}))
+        return k.pallas(*args, interpret=(backend == "interpret"), **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -15,7 +15,10 @@ intermediate through DRAM.  This module adds the opt-in fast path:
   the active backend **once** and returns a cached :class:`Executor`:
 
   - ``xla``/``interpret``/``pallas`` — the whole chain replays inside a
-    single ``jax.jit``, so repeated calls never re-trace;
+    single ``jax.jit``, so repeated calls never re-trace.  The executable
+    carries the program's name (``jit_<name>``) and each node's device ops
+    the name scope ``n<idx>/<kernel>``; ``Executor.__call__`` is a
+    ``program.call`` profiler span on the host;
   - ``pimsab`` — the chain becomes one ``tensor_dsl.WorkloadGraph`` and is
     distributed/allocated/codegen'd jointly (``pimsab_backend``): integer
     producer→consumer intermediates stay CRAM-resident and the DRAM
@@ -38,8 +41,10 @@ from __future__ import annotations
 
 import contextvars
 import hashlib
+import re
 import threading
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -435,6 +440,9 @@ class Executor:
         compatible handles only — the executor validates at run time)."""
         self.states = dict(states)
 
+    # a host span over the flattening, the argument checks and the dispatch;
+    # the device runs the executable after (and partly during) it
+    @partial(jax.profiler.annotate_function, name="program.call")
     def __call__(self, *args, **kwargs):
         leaves, in_tree = jax.tree_util.tree_flatten((args, kwargs))
         if in_tree != self.program.in_tree:
@@ -515,6 +523,12 @@ def cached_executable(key: Any, build: Callable[[], Any],
     return artifact
 
 
+def _identifier(name: str) -> str:
+    """``name`` as a Python identifier: other characters become ``_``."""
+    ident = re.sub(r"[^0-9A-Za-z_]", "_", name) or "program"
+    return ident if not ident[0].isdigit() else "_" + ident
+
+
 def _jax_run(program: Program, backend: str) -> Callable[[List[Any]], Any]:
     """Replay the whole program inside one jitted function (compile-once for
     the jax-side backends)."""
@@ -534,13 +548,16 @@ def _jax_run(program: Program, backend: str) -> Callable[[List[Any]], Any]:
         with api.use_backend(backend):
             for idx, op in enumerate(program.ops):
                 vals = [resolve(r) for r in op.inputs]
-                env[idx] = api.dispatch(
-                    op.kernel, *vals,
-                    pallas_kwargs=dict(op.pallas_kwargs) or None,
-                    **dict(op.kwargs),
-                )
+                with jax.named_scope(f"n{idx}"):
+                    env[idx] = api.dispatch(
+                        op.kernel, *vals,
+                        pallas_kwargs=dict(op.pallas_kwargs) or None,
+                        **dict(op.kwargs),
+                    )
         return [resolve(r) for r in program.out_refs]
 
+    # the executable is named after the program: jit_<name>
+    replay.__name__ = replay.__qualname__ = _identifier(program.name)
     jitted = jax.jit(replay)
     consts = [np.asarray(c) for c in program.consts]
     return lambda leaves: jitted(leaves, consts)

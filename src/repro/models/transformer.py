@@ -8,6 +8,12 @@ group axis) so the HLO stays depth-independent.  Three entry points:
 * ``forward``     — full-sequence logits (training / evaluation).
 * ``prefill``     — full-sequence forward that also returns the decode cache.
 * ``decode_step`` — one token in, one token out, cache updated in place.
+
+Each block's attention and feed-forward run under the ``jax.named_scope``
+``attn`` and ``ffn``, the logits under ``lm_head``, and the decode step's
+cache update under ``attn/kv_write``: a profiler trace's device ops carry
+these names, so a step's device time splits by part (ops outside them, such
+as norms and the scan's stacking of the cache, carry none).
 """
 from __future__ import annotations
 
@@ -262,7 +268,8 @@ def _block_apply_seq(
     h = rmsnorm(p["ln1"], x, cfg.norm_eps)
     cache_out: Params = {}
     if kind in ("attn", "local_attn"):
-        y, kv = _attn_apply(p["attn"], h, cfg, flags, positions, kind, causal)
+        with jax.named_scope("attn"):
+            y, kv = _attn_apply(p["attn"], h, cfg, flags, positions, kind, causal)
         cache_out.update(kv)
     elif kind == "rglru":
         y, st = rglru_block_apply(p["mixer"], h, cfg, states)
@@ -281,7 +288,8 @@ def _block_apply_seq(
         cache_out["cross_k"], cache_out["cross_v"] = kvx["k"], kvx["v"]
     if "ffn" in p:
         h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        y2, a = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
+        with jax.named_scope("ffn"):
+            y2, a = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
         x = x + y2
         aux = aux + a
     return x, cache_out, aux
@@ -374,9 +382,10 @@ def forward(
 
 
 def _lm_head(params: Params, x: jnp.ndarray, cfg) -> jnp.ndarray:
-    if cfg.tie_embeddings:
-        return x @ params["embed"]["w"].T
-    return linear(params["lm_head"], x)  # handles the int8 bit-sliced head
+    with jax.named_scope("lm_head"):
+        if cfg.tie_embeddings:
+            return x @ params["embed"]["w"].T
+        return linear(params["lm_head"], x)  # handles the int8 bit-sliced head
 
 
 def loss_fn(params, cfg, batch, flags=DEFAULT_FLAGS, rules=None):
@@ -571,19 +580,21 @@ def _attn_decode(p, h, cfg, entry, pos, kind, rules):
         valid = (pos + 1) * jnp.ones((b,), jnp.int32)
     new_entry = dict(entry)
     if "k_scale" in entry:  # int8 KV cache (PIMSAB adaptive precision)
-        kq, ks = quantize_kv(k, KV_SPEC)
-        vq, vs = quantize_kv(v, KV_SPEC)
-        new_entry["k"] = jax.lax.dynamic_update_slice_in_dim(entry["k"], kq, slot, axis=1)
-        new_entry["v"] = jax.lax.dynamic_update_slice_in_dim(entry["v"], vq, slot, axis=1)
-        new_entry["k_scale"] = jax.lax.dynamic_update_slice_in_dim(entry["k_scale"], ks, slot, axis=1)
-        new_entry["v_scale"] = jax.lax.dynamic_update_slice_in_dim(entry["v_scale"], vs, slot, axis=1)
+        with jax.named_scope("kv_write"):
+            kq, ks = quantize_kv(k, KV_SPEC)
+            vq, vs = quantize_kv(v, KV_SPEC)
+            new_entry["k"] = jax.lax.dynamic_update_slice_in_dim(entry["k"], kq, slot, axis=1)
+            new_entry["v"] = jax.lax.dynamic_update_slice_in_dim(entry["v"], vq, slot, axis=1)
+            new_entry["k_scale"] = jax.lax.dynamic_update_slice_in_dim(entry["k_scale"], ks, slot, axis=1)
+            new_entry["v_scale"] = jax.lax.dynamic_update_slice_in_dim(entry["v_scale"], vs, slot, axis=1)
         out = decode_attention_int8(
             q, new_entry["k"], new_entry["v"], new_entry["k_scale"], new_entry["v_scale"],
             valid, KV_SPEC,
         )
     else:
-        new_entry["k"] = jax.lax.dynamic_update_slice_in_dim(entry["k"], k, slot, axis=1)
-        new_entry["v"] = jax.lax.dynamic_update_slice_in_dim(entry["v"], v, slot, axis=1)
+        with jax.named_scope("kv_write"):
+            new_entry["k"] = jax.lax.dynamic_update_slice_in_dim(entry["k"], k, slot, axis=1)
+            new_entry["v"] = jax.lax.dynamic_update_slice_in_dim(entry["v"], v, slot, axis=1)
         out = decode_attention(q, new_entry["k"], new_entry["v"], valid)
     y = linear(p["wo"], out.reshape(b, 1, cfg.q_dim))
     return y, new_entry
@@ -611,7 +622,8 @@ def decode_step(
             p, entry = gp[key], gcache[key]
             h = rmsnorm(p["ln1"], x, cfg.norm_eps)
             if kind in ("attn", "local_attn"):
-                y, new_entry = _attn_decode(p["attn"], h, cfg, entry, pos, kind, rules)
+                with jax.named_scope("attn"):
+                    y, new_entry = _attn_decode(p["attn"], h, cfg, entry, pos, kind, rules)
             elif kind == "rglru":
                 y, st = rglru_block_apply(p["mixer"], h, cfg, entry)
                 new_entry = st
@@ -631,7 +643,8 @@ def decode_step(
                 new_entry["cross_k"], new_entry["cross_v"] = entry["cross_k"], entry["cross_v"]
             if "ffn" in p:
                 h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-                y2, _ = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
+                with jax.named_scope("ffn"):
+                    y2, _ = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
                 x = x + y2
             new_entries[key] = new_entry
         return x, new_entries

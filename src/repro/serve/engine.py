@@ -12,6 +12,12 @@ API's global compile cache (``repro.kernels.program.cached_executable``, the
 same cache backing ``api.compile``): constructing a second ServeEngine with
 the same (config, flags, backend, max_len) reuses the jitted steps instead
 of re-tracing/re-lowering them — visible in ``api.compile_cache_info()``.
+Their executables are named ``jit_prefill_step`` and ``jit_decode_step``.
+
+:meth:`ServeEngine.run` marks its phases with ``jax.profiler``
+``TraceAnnotation`` spans (``serve.*``, see there) and keeps
+:class:`ServeCounters`; with no profiler running a span costs under a
+microsecond and records nothing.
 """
 from __future__ import annotations
 
@@ -24,6 +30,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -32,12 +39,8 @@ from repro.kernels.api import use_backend
 from repro.kernels.program import cached_executable
 from repro.models.common import maybe_quantize_tree
 from repro.models.runtime import DEFAULT_FLAGS, RunFlags
-from repro.models.transformer import (
-    cache_shape,
-    decode_step,
-    init_cache,
-    prefill,
-)
+from repro.models import transformer
+from repro.models.transformer import cache_shape, init_cache, prefill
 
 
 def _backend_scope(backend: Optional[str]):
@@ -72,19 +75,19 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int, rules: MeshRules, fl
 
 
 def make_prefill_step(cfg, flags=DEFAULT_FLAGS, rules=None, max_len=None, backend=None) -> Callable:
-    def step(params, batch):
+    def prefill_step(params, batch):
         with _backend_scope(backend):
             return prefill(params, cfg, batch, flags, rules, max_len=max_len)
 
-    return step
+    return prefill_step
 
 
 def make_decode_step(cfg, flags=DEFAULT_FLAGS, rules=None, backend=None) -> Callable:
-    def step(params, cache, tokens):
+    def decode_step(params, cache, tokens):
         with _backend_scope(backend):
-            return decode_step(params, cfg, cache, tokens, flags, rules)
+            return transformer.decode_step(params, cfg, cache, tokens, flags, rules)
 
-    return step
+    return decode_step
 
 
 # ---------------------------------------------------------------------------
@@ -99,6 +102,22 @@ class Request:
     max_new_tokens: int
     generated: List[int] = field(default_factory=list)
     done: bool = False
+
+
+@dataclass
+class ServeCounters:
+    """What an engine's decode loops did since it was built.
+
+    ``decode_steps`` counts jitted decode steps, ``lane_steps`` the batch
+    lanes they ran (batch x steps of each run), ``useful_lane_steps`` those
+    lanes whose request still wanted a token.  ``useful_lane_steps /
+    lane_steps`` is the share of decode work that served a request: a
+    lock-step batch keeps decoding its retired lanes until its longest
+    request ends.  Take differences of two readings to count an interval."""
+
+    decode_steps: int = 0
+    lane_steps: int = 0
+    useful_lane_steps: int = 0
 
 
 class ServeEngine:
@@ -139,6 +158,7 @@ class ServeEngine:
             ("serve_step", "decode", repr(cfg), repr(flags), backend),
             lambda: jax.jit(make_decode_step(cfg, flags, backend=backend)),
         )
+        self.counters = ServeCounters()
 
     def pack(self, requests: List[Request]) -> Dict[str, jnp.ndarray]:
         """The prefill batch: prompts left-padded to one length (at least 8)."""
@@ -160,22 +180,49 @@ class ServeEngine:
         return np.array(jnp.argmax(logits[:, : self.cfg.vocab_size], axis=-1), np.int32)
 
     def run(self, requests: List[Request]) -> List[Request]:
-        cache, logits = self.prefill_step(self.params, self.pack(requests))
-        steps = max(r.max_new_tokens for r in requests)
-        next_tok = self._greedy(logits)
-        for _ in range(steps):
-            for i, r in enumerate(requests):
-                if not r.done:
-                    t = int(next_tok[i])
-                    r.generated.append(t)
-                    if t == self.eos or len(r.generated) >= r.max_new_tokens:
-                        r.done = True
-                if r.done:
-                    # retired lane: its stale argmax must not keep decoding —
-                    # feed the pad id so the lock-step cache stays clean
-                    next_tok[i] = 0
-            if all(r.done for r in requests):
-                break
-            cache, logits = self.decode_step(self.params, cache, jnp.asarray(next_tok)[:, None])
-            next_tok = self._greedy(logits)
+        """Serve one batch to the end of its longest request.
+
+        Host spans, nested under ``serve.run`` (the whole call):
+        ``serve.pack`` builds the prefill batch; ``serve.prefill`` dispatches
+        the prefill step; per decode step, ``serve.retire`` hands each lane's
+        token to its request and retires finished lanes, ``serve.decode``
+        uploads the tokens and dispatches the decode step, and
+        ``serve.sample`` picks the next tokens and waits for them on the
+        host (after the prefill too).  Device time that no op fills while
+        the host is inside one of them is that phase's cost to the chip."""
+        with TraceAnnotation("serve.run"):
+            with TraceAnnotation("serve.pack"):
+                batch = self.pack(requests)
+            with TraceAnnotation("serve.prefill"):
+                cache, logits = self.prefill_step(self.params, batch)
+            with TraceAnnotation("serve.sample"):
+                next_tok = self._greedy(logits)
+            steps = max(r.max_new_tokens for r in requests)
+            before = [len(r.generated) for r in requests]
+            decode_steps = 0
+            for _ in range(steps):
+                with TraceAnnotation("serve.retire"):
+                    for i, r in enumerate(requests):
+                        if not r.done:
+                            t = int(next_tok[i])
+                            r.generated.append(t)
+                            if t == self.eos or len(r.generated) >= r.max_new_tokens:
+                                r.done = True
+                        if r.done:
+                            # retired lane: its stale argmax must not keep decoding —
+                            # feed the pad id so the lock-step cache stays clean
+                            next_tok[i] = 0
+                    if all(r.done for r in requests):
+                        break
+                with TraceAnnotation("serve.decode"):
+                    cache, logits = self.decode_step(self.params, cache, jnp.asarray(next_tok)[:, None])
+                with TraceAnnotation("serve.sample"):
+                    next_tok = self._greedy(logits)
+                decode_steps += 1
+        # every token after a request's first came out of a decode step
+        c = self.counters
+        c.decode_steps += decode_steps
+        c.lane_steps += len(requests) * decode_steps
+        c.useful_lane_steps += sum(max(len(r.generated) - n - 1, 0)
+                                   for r, n in zip(requests, before))
         return requests
