@@ -8,8 +8,9 @@ zero-bit skipping = statically dropping all-zero slice pairs
 (``api.skip_pairs`` / ``api.zero_slice_pairs`` compute them from concrete
 operands at trace time).
 
-The same kernel carries every integer matmul in the registry (``conv2d``,
-``int_matmul``, the attention kernels): the MXU has no int32 × int32 path, so
+The same kernel carries the registry's other integer matmuls (``int_matmul``,
+the attention kernels; ``conv2d`` sums its taps in its own kernel in
+``kernels/conv.py``): the MXU has no int32 × int32 path, so
 :func:`wide_matmul` splits int32 operands into int8 slices and sums the
 shifted slice-pair products in int32, which wraps mod 2³² exactly like the
 int32 oracles.

@@ -119,6 +119,46 @@ _AWKWARD = {
     "conv2d_hint_edge": lambda: (
         "conv2d", (jnp.full((1, 2, 6, 6), 32767, jnp.int32), _full((3, 2, 3, 3), 7, -3, 4)),
         {"stride": 1, "padding": 1, "x_bits": 16, "w_bits": 3}),
+    # conv2d's two paths (``kernels.conv``): taps for C >= 64 or a 1x1 kernel,
+    # a patch slab for narrower C; 1, 2 and 4 digits (x_bits 8, 16, none)
+    "conv2d_taps_s1_p1_odd9_x16": lambda: (
+        "conv2d", (_full((2, 64, 9, 9), 25, -(1 << 15), 1 << 15),
+                   _full((16, 64, 3, 3), 26, -128, 128)),
+        {"stride": 1, "padding": 1, "x_bits": 16, "w_bits": 8}),
+    "conv2d_taps_s1_p0_odd7_near_wrap": lambda: (
+        "conv2d", (_near_wrap((2, 64, 7, 7), 27), _near_wrap((8, 64, 3, 3), 28)),
+        {"stride": 1, "padding": 0}),
+    "conv2d_taps_s2_p1_odd7_near_wrap": lambda: (
+        "conv2d", (_near_wrap((2, 64, 7, 7), 29), _full((8, 64, 3, 3), 30, -128, 128)),
+        {"stride": 2, "padding": 1, "w_bits": 8}),
+    "conv2d_taps_s2_p1_even8_x8": lambda: (
+        "conv2d", (_full((1, 72, 8, 8), 31, -128, 128), _full((24, 72, 3, 3), 32, -128, 128)),
+        {"stride": 2, "padding": 1, "x_bits": 8, "w_bits": 8}),
+    "conv2d_taps_1x1_s2_odd9_x8": lambda: (
+        "conv2d", (_full((2, 64, 9, 9), 33, -128, 128), _full((24, 64, 1, 1), 34, -128, 128)),
+        {"stride": 2, "padding": 0, "x_bits": 8, "w_bits": 8}),
+    "conv2d_taps_1x1_s1_c3_near_wrap": lambda: (
+        "conv2d", (_near_wrap((2, 3, 7, 7), 35), _near_wrap((5, 3, 1, 1), 36)),
+        {"stride": 1, "padding": 0}),
+    # 2 x 35 x 35 grid rows at 4 digits of 64 channels: two row blocks that
+    # do not divide it, each with its halo
+    "conv2d_taps_two_row_blocks": lambda: (
+        "conv2d", (_near_wrap((2, 64, 33, 33), 37), _full((8, 64, 3, 3), 38, -128, 128)),
+        {"stride": 1, "padding": 1, "w_bits": 8}),
+    # C = 640 > 512: the channel sweep accumulates over two C blocks
+    "conv2d_taps_c640_sweep": lambda: (
+        "conv2d", (_near_wrap((1, 640, 5, 5), 39), _full((8, 640, 3, 3), 40, -128, 128)),
+        {"stride": 1, "padding": 1, "w_bits": 8}),
+    "conv2d_patches_c3_s1_p1_x8": lambda: (
+        "conv2d", (_full((2, 3, 9, 9), 41, -128, 128), _full((16, 3, 3, 3), 42, -128, 128)),
+        {"stride": 1, "padding": 1, "x_bits": 8, "w_bits": 8}),
+    "conv2d_patches_c3_s2_p0_odd9_near_wrap": lambda: (
+        "conv2d", (_near_wrap((2, 3, 9, 9), 43), _near_wrap((8, 3, 3, 3), 44)),
+        {"stride": 2, "padding": 0}),
+    "conv2d_patches_c3_s2_p1_odd7_x16": lambda: (
+        "conv2d", (_full((1, 3, 7, 7), 45, -(1 << 15), 1 << 15),
+                   _full((8, 3, 3, 3), 46, -128, 128)),
+        {"stride": 2, "padding": 1, "x_bits": 16, "w_bits": 8}),
     "attention_qk_near_wrap": lambda: (
         "attention_qk", (_near_wrap((5, 64), 8), _near_wrap((37, 64), 9)), {}),
     "attention_pv_full_range": lambda: (
@@ -157,6 +197,75 @@ def test_kernel_awkward_shape_bit_exact(case):
         got = api.dispatch(name, *args, **kwargs)
     assert got.dtype == want.dtype and got.shape == want.shape
     np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("n", [1, 2, 3, 4])
+def test_conv_digits_equal_int_slices(n):
+    """The conv wrapper's one-pass digit split gives ``int_slices``' digits
+    for every int32 value, in range of the hint or not."""
+    from repro.kernels.bitslice_matmul import int_slices
+    from repro.kernels.conv import _digits
+
+    edge = np.array([_I32[0], _I32[1], 0, -1, 1, 127, 128, -128, -129, 32767, 32768, -32768,
+                     -32769, (1 << 23) - 1, 1 << 23, -(1 << 23), -(1 << 23) - 1], np.int64)
+    rnd = np.random.default_rng(n).integers(_I32[0], _I32[1], 5000)
+    x = jnp.asarray(np.concatenate([edge, rnd]).astype(np.int32))
+    np.testing.assert_array_equal(np.stack(_digits(x, n)), np.asarray(int_slices(x, n)))
+
+
+def _conv_paths(x, w, **kwargs):
+    from repro.kernels import conv
+
+    conv.reset_path_counts()
+    with use_backend("interpret"):
+        jax.eval_shape(lambda x, w: api.conv2d(x, w, **kwargs), x, w)
+    return conv.path_counts()
+
+
+@pytest.mark.parametrize("c,k,path", [(3, 3, "patches"), (32, 3, "patches"), (64, 3, "taps"),
+                                      (3, 1, "taps"), (512, 3, "taps")])
+def test_conv2d_path_follows_channels(c, k, path):
+    x = jax.ShapeDtypeStruct((1, c, 8, 8), jnp.int32)
+    w = jax.ShapeDtypeStruct((4, c, k, k), jnp.int32)
+    assert _conv_paths(x, w, padding=k // 2) == {path: 1}
+
+
+def test_conv2d_lowers_without_gather_or_patch_matrix():
+    """A ResNet-18 stage-1 conv at batch 64, lowered for the TPU (nothing
+    runs): no gather, and no int32 value over twice the activation (the
+    im2col matrix was nine times it)."""
+    import math
+    import re
+
+    x = jax.ShapeDtypeStruct((64, 64, 56, 56), jnp.int32)
+    w = jax.ShapeDtypeStruct((64, 64, 3, 3), jnp.int32)
+    with use_backend("pallas"):
+        text = jax.jit(
+            lambda x, w: api.conv2d(x, w, stride=1, padding=1, x_bits=21, w_bits=8)
+        ).trace(x, w).lower(lowering_platforms=("tpu",)).as_text()
+    assert "gather" not in text
+    assert text.count("tpu_custom_call") == 1
+    int32_sizes = [math.prod(int(d) for d in m.split("x"))
+                   for m in re.findall(r"tensor<([0-9x]+)xi32>", text)]
+    assert max(int32_sizes) <= 2 * math.prod(x.shape)
+
+
+def test_resnet18_lowers_19_taps_1_patches_no_gather():
+    """The ResNet-18 Program lowered for the TPU (nothing runs): the stem
+    takes the patch slab, the 19 other convs the taps, and no conv gathers."""
+    from repro.kernels import conv
+    from repro.models import resnet
+
+    cfg = resnet.RESNET18
+    params, x = jax.eval_shape(lambda: (resnet.init_params(cfg), resnet.make_input(cfg, batch=2)))
+    traced = api.trace(lambda p, x: resnet.forward(cfg, p, x), name="resnet18")
+    conv.reset_path_counts()
+    with use_backend("pallas"):
+        ex = api.compile(traced.trace(params, x))
+        text = jax.jit(lambda p, x: ex(p, x)).trace(params, x).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert conv.path_counts() == {"taps": 19, "patches": 1}
+    assert "gather" not in text
 
 
 def test_rglru_scan_padded_blocks():
