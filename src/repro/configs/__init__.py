@@ -60,12 +60,15 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
     """A tiny same-family config for CPU smoke tests.
 
     Shrinks depth/width/experts/vocab but keeps the block pattern family,
-    GQA ratio, bias/tie/frontend flags — i.e. everything that changes code
-    paths — intact.
+    GQA ratio, bias/tie/frontend flags, routing, latent attention, YaRN
+    and a leading dense layer — i.e. everything that changes code paths —
+    intact.
     """
     pat = tuple(dict.fromkeys(cfg.block_pattern))  # unique kinds, order kept
-    # keep at least one of each kind; two pattern groups
-    n_layers = 2 * len(pat)
+    # keep at least one of each kind; two pattern groups (after one leading
+    # dense layer, where the model has them)
+    first_dense = min(cfg.first_dense_layers, 1)
+    n_layers = first_dense + 2 * len(pat)
     n_heads = min(cfg.n_heads, 4)
     n_kv = max(1, min(cfg.n_kv_heads, n_heads))
     while n_heads % n_kv:
@@ -85,6 +88,13 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         window=min(cfg.window, 8) if cfg.window else 0,
         n_experts=min(cfg.n_experts, 4) if cfg.n_experts else 0,
         experts_per_token=min(cfg.experts_per_token, 2) if cfg.n_experts else 0,
+        held_experts=0,
+        first_held_expert=0,
+        first_dense_layers=first_dense,
+        dense_d_ff=6 * head_dim if first_dense else 0,
+        mla=dataclasses.replace(cfg.mla, q_lora_rank=2 * head_dim, kv_lora_rank=head_dim,
+                                qk_nope_head_dim=head_dim, qk_rope_head_dim=head_dim // 2,
+                                v_head_dim=head_dim) if cfg.mla else None,
         n_enc_layers=2 if cfg.n_enc_layers else 0,
         enc_seq_len=8,
         n_patches=4,

@@ -13,7 +13,6 @@ CONFIG = ModelConfig(
     block_pattern=("attn",),
     n_experts=16,
     experts_per_token=4,
-    moe_capacity_factor=1.25,
     rope_theta=500_000.0,
     quant=QuantConfig(enabled=True, act_bits=8, weight_bits=8),
     source="[hf:databricks/dbrx-base; unverified]",

@@ -96,13 +96,14 @@ def _matmul_leaf_spec(path: Tuple[str, ...], shape, cfg, rules: MeshRules) -> P:
 
     Stacked block leaves carry a leading scan-group axis which never shards;
     the matmul dims follow the Megatron pattern: column-parallel in
-    (wq/wk/wv, w_gate/w_up, embed), row-parallel out (wo, w_down), experts
-    on the TP axis for MoE.
+    (wq/wk/wv, latent attention's wq_b/wkv_b, w_gate/w_up, embed),
+    row-parallel out (wo, w_down), experts on the TP axis for MoE; the
+    latent projections (wq_a, wkv_a) and the router replicate.
     """
-    grouped = path[0] in ("blocks", "enc_blocks")
+    grouped = path[0] in ("blocks", "dense_blocks", "enc_blocks")
     ndim = len(shape)
-    # {"w": ...} leaf-dicts name the layer one level up; raw leaves (the MoE
-    # expert stacks) name it directly
+    # {"w": ...} leaf-dicts name the layer one level up; raw leaves name it
+    # directly
     owner = path[-1]
     if owner in ("w", "w_q") and len(path) >= 2:
         owner = path[-2]
@@ -115,19 +116,18 @@ def _matmul_leaf_spec(path: Tuple[str, ...], shape, cfg, rules: MeshRules) -> P:
         return P(_tp_both(rules, cfg.padded_vocab(), shape[0], "vocab"), None)
     if owner == "lm_head":
         return P(None, _tp_both(rules, cfg.padded_vocab(), shape[-1], "vocab"))
-    if owner == "wq":
+    if owner in ("wq", "wq_b", "wkv_b"):  # latent attention: heads out of the latents
         return spec(None, _tp_both(rules, cfg.n_heads, shape[-1], "q_heads"))
     if owner in ("wk", "wv"):
         return spec(None, _tp_both(rules, cfg.n_kv_heads, shape[-1], "kv_heads"))
     if owner == "wo":
         return spec(_tp_both(rules, cfg.n_heads, shape[-2], "q_heads"), None)
+    if owner in ("w_gate", "w_up", "w_down") and ndim - (1 if grouped else 0) == 3:
+        # MoE: (E, d_in, d_out) → shard the experts held here
+        return spec(_tp_both(rules, cfg.n_held_experts, shape[-3], "experts"), None, None)
     if owner in ("w_gate", "w_up"):
-        if ndim - (1 if grouped else 0) == 3:  # MoE: (E, d, f) → shard experts
-            return spec(_tp_both(rules, cfg.n_experts, shape[-3], "experts"), None, None)
         return spec(None, _tp_both(rules, cfg.d_ff, shape[-1], "d_ff"))
     if owner == "w_down":
-        if ndim - (1 if grouped else 0) == 3:
-            return spec(_tp_both(rules, cfg.n_experts, shape[-3], "experts"), None, None)
         return spec(_tp_both(rules, cfg.d_ff, shape[-2], "d_ff"), None)
     return _rep(ndim)
 

@@ -92,7 +92,7 @@ def _scan_corrected_costs(cfg, cell, mesh, rules, flags, measured):
     u = []
     for k in (1, 2):
         small = dc.replace(
-            cfg, n_layers=plen * k, n_enc_layers=(k if cfg.n_enc_layers else 0)
+            cfg, n_layers=cfg.first_dense_layers + plen * k, n_enc_layers=(k if cfg.n_enc_layers else 0)
         )
         fl = dc.replace(flags, scan_layers=False)
         u.append(_lower_costs(small, cell, mesh, rules, fl))

@@ -32,25 +32,30 @@ def _gqa_fold(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
     return q.reshape(b, s, n_kv, hq // n_kv, d)
 
 
-def _direct_attention(q, k, v, mask) -> jnp.ndarray:
-    """q: (B,S,Hkv,G,d); k,v: (B,T,Hkv,d); mask: (S,T) bool or None."""
+def _scaled(scores, d: int, scale: Optional[float]):
+    """Scores times the softmax scale: 1/sqrt(d) unless ``scale`` is given."""
+    return scores / math.sqrt(d) if scale is None else scores * scale
+
+
+def _direct_attention(q, k, v, mask, scale: Optional[float] = None) -> jnp.ndarray:
+    """q: (B,S,Hkv,G,d); k: (B,T,Hkv,d); v: (B,T,Hkv,dv); mask: (S,T) bool or None."""
     d = q.shape[-1]
     scores = jnp.einsum("bshgd,bthd->bhgst", q, k).astype(jnp.float32)
-    scores = scores / math.sqrt(d)
+    scores = _scaled(scores, d, scale)
     if mask is not None:
         scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
     return jnp.einsum("bhgst,bthd->bshgd", probs, v)
 
 
-def _chunk_update(carry, qc, kc, vc, mask):
+def _chunk_update(carry, qc, kc, vc, mask, scale: Optional[float] = None):
     """Online-softmax update for one (q-chunk, kv-chunk) pair.
 
     carry = (m, l, acc): running max (B,H,G,Sq), denom, accumulator.
     """
     m, l, acc = carry
     d = qc.shape[-1]
-    s = jnp.einsum("bshgd,bthd->bhgst", qc, kc).astype(jnp.float32) / math.sqrt(d)
+    s = _scaled(jnp.einsum("bshgd,bthd->bhgst", qc, kc).astype(jnp.float32), d, scale)
     if mask is not None:
         s = jnp.where(mask[None, None, None], s, NEG_INF)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1))
@@ -92,16 +97,24 @@ def _pair_mask(i: int, j: int, chunk: int, causal: bool, window: int):
 
 
 def chunked_attention(
-    q, k, v, *, causal: bool, chunk: int, triangular: bool, window: int = 0
+    q, k, v, *, causal: bool, chunk: int, triangular: bool, window: int = 0,
+    scale: Optional[float] = None,
 ) -> jnp.ndarray:
-    """Flash-style (banded) attention.  q: (B,S,Hkv,G,d); k,v: (B,T,Hkv,d).
+    """Flash-style (banded) attention.  q: (B,S,Hkv,G,d); k: (B,T,Hkv,d);
+    v: (B,T,Hkv,dv).
 
     Python loop over q-chunks (static), lax.scan over unmasked interior
     kv-chunks.  ``triangular`` skips j > i chunks for causal attention (no
     masked-out FLOPs issued); ``window`` > 0 additionally skips chunks fully
     outside the local-attention band — O(S·W) instead of O(S²).
     """
-    b, s, hkv, g, d = q.shape
+    b, s, hkv, g, _ = q.shape
+    d = v.shape[-1]
+    update_nomask, update_masked = _chunk_update_nomask, _chunk_update_masked
+    if scale is not None:
+        update_masked = jax.checkpoint(partial(_chunk_update, scale=scale))
+        update_nomask = jax.checkpoint(
+            lambda carry, qc, kc, vc: _chunk_update(carry, qc, kc, vc, None, scale))
     t = k.shape[1]
     assert s % chunk == 0, (s, chunk)
     t_pad = (-t) % chunk
@@ -111,7 +124,7 @@ def chunked_attention(
         v = jnp.pad(v, ((0, 0), (0, t_pad), (0, 0), (0, 0)))
     nq, nk = s // chunk, (t + t_pad) // chunk
     valid_t = t
-    k_chunks = k.reshape(b, nk, chunk, hkv, d)
+    k_chunks = k.reshape(b, nk, chunk, hkv, k.shape[-1])
     v_chunks = v.reshape(b, nk, chunk, hkv, d)
 
     def pair_mask(i, j):
@@ -142,11 +155,11 @@ def chunked_attention(
 
                 def body(carry, kv):
                     kc, vc = kv
-                    return _chunk_update_nomask(carry, qc, kc, vc), None
+                    return update_nomask(carry, qc, kc, vc), None
 
                 (m, l, acc), _ = jax.lax.scan(body, (m, l, acc), (sel_k, sel_v))
             for j in masked_js:
-                m, l, acc = _chunk_update_masked(
+                m, l, acc = update_masked(
                     (m, l, acc), qc, k_chunks[:, j], v_chunks[:, j], pair_mask(i, j)
                 )
         else:
@@ -155,29 +168,31 @@ def chunked_attention(
             for j in range(lo, hi):
                 mask = pair_mask(i, j)
                 if mask is None:
-                    m, l, acc = _chunk_update_nomask((m, l, acc), qc, k_chunks[:, j], v_chunks[:, j])
+                    m, l, acc = update_nomask((m, l, acc), qc, k_chunks[:, j], v_chunks[:, j])
                 else:
-                    m, l, acc = _chunk_update_masked((m, l, acc), qc, k_chunks[:, j], v_chunks[:, j], mask)
+                    m, l, acc = update_masked((m, l, acc), qc, k_chunks[:, j], v_chunks[:, j], mask)
         out = acc / jnp.moveaxis(l, -1, 1)[..., None]
         outs.append(out.astype(q.dtype))
     return jnp.concatenate(outs, axis=1)
 
 
-def full_attention(q, k, v, *, causal: bool, chunk: int, triangular: bool, flash_threshold: int, window: int = 0) -> jnp.ndarray:
-    """Entry point.  q: (B,S,Hq,d) -> (B,S,Hq,d); k,v: (B,T,Hkv,d)."""
-    b, s, hq, d = q.shape
-    hkv = k.shape[2]
+def full_attention(q, k, v, *, causal: bool, chunk: int, triangular: bool, flash_threshold: int,
+                   window: int = 0, scale: Optional[float] = None) -> jnp.ndarray:
+    """Entry point.  q: (B,S,Hq,d), k: (B,T,Hkv,d), v: (B,T,Hkv,dv) ->
+    (B,S,Hq,dv).  Scores are scaled by ``scale``, 1/sqrt(d) by default."""
+    b, s, hq, _ = q.shape
+    hkv, d = k.shape[2], v.shape[-1]
     qf = _gqa_fold(q, hkv)
     if s <= flash_threshold and k.shape[1] <= flash_threshold and not window:
         mask = None
         if causal:
             t = k.shape[1]
             mask = (jnp.arange(s)[:, None] + (t - s)) >= jnp.arange(t)[None, :]
-        out = _direct_attention(qf, k, v, mask)
+        out = _direct_attention(qf, k, v, mask, scale)
     else:
         cw = min(chunk, s)
         out = chunked_attention(
-            qf, k, v, causal=causal, chunk=cw, triangular=triangular, window=window
+            qf, k, v, causal=causal, chunk=cw, triangular=triangular, window=window, scale=scale
         )
     return out.reshape(b, s, hq, d)
 

@@ -61,6 +61,31 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor (DeepSeek-V3's ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, yarn) -> jnp.ndarray:
+    """YaRN's inverse frequencies over ``dim`` rotary dims (``dim / 2`` of
+    them), as DeepSeek-V3's ``DeepseekV3YarnRotaryEmbedding`` builds them:
+    the dims that turn fewer than ``beta_slow`` times over the original
+    context are interpolated by ``factor``, those that turn more than
+    ``beta_fast`` times are kept, with a linear ramp between."""
+
+    def correction_dim(rotations):
+        return dim * math.log(yarn.original_max_position / (rotations * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    extra = rope_freqs(dim, theta)
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / (high - low), 0, 1)
+    return extra / yarn.factor * ramp + extra * (1 - ramp)
+
+
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
     """x: (..., S, H, hd); positions: broadcastable to (..., S)."""
     hd = x.shape[-1]
@@ -133,6 +158,14 @@ def quant_linear(
     if "b" in p:
         out = out + p["b"].astype(jnp.float32)
     return out.astype(x.dtype)
+
+
+def dequantized(p: Params, dtype) -> jnp.ndarray:
+    """A linear's weight in ``dtype``, whether it is stored plain or as int8
+    with its scales (for products with activations that are not quantized)."""
+    if "w_q" in p:
+        return (p["w_q"].astype(jnp.float32) * p["w_scale"]).astype(dtype)
+    return p["w"].astype(dtype)
 
 
 def linear(
@@ -213,8 +246,9 @@ def maybe_quantize_tree(params, cfg, path: str = "") -> Any:
     def rec(node, path):
         if isinstance(node, dict):
             # ndim 2 = plain linear; ndim 3 = scan-stacked (G, d_in, d_out) —
-            # per-group quantization; lax.scan slices both w_q and w_scale
-            if "w" in node and node["w"].ndim in (2, 3) and not any(s in path for s in skip):
+            # per-group quantization; lax.scan slices both w_q and w_scale;
+            # ndim 4 = scan-stacked experts (G, E, d_in, d_out)
+            if "w" in node and node["w"].ndim in (2, 3, 4) and not any(s in path for s in skip):
                 q = quantize_weight(node["w"], spec.weight_bits)
                 if "b" in node:
                     q["b"] = node["b"]
@@ -232,6 +266,20 @@ def maybe_quantize_tree(params, cfg, path: str = "") -> Any:
 
 def swiglu(gate: jnp.ndarray, up: jnp.ndarray) -> jnp.ndarray:
     return jax.nn.silu(gate.astype(jnp.float32)).astype(gate.dtype) * up
+
+
+def swiglu_init(key, d: int, f: int, dtype) -> Params:
+    ks = jax.random.split(key, 3)
+    return {
+        "w_gate": linear_init(ks[0], d, f, dtype),
+        "w_up": linear_init(ks[1], d, f, dtype),
+        "w_down": linear_init(ks[2], f, d, dtype),
+    }
+
+
+def swiglu_ffn(p: Params, x: jnp.ndarray) -> jnp.ndarray:
+    """The gated SwiGLU MLP ``W_down(silu(W_gate x) * W_up x)``."""
+    return linear(p["w_down"], swiglu(linear(p["w_gate"], x), linear(p["w_up"], x)))
 
 
 def softmax_cross_entropy(logits: jnp.ndarray, labels: jnp.ndarray, vocab: int) -> jnp.ndarray:

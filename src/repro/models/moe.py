@@ -1,92 +1,157 @@
-"""Mixture-of-Experts FFN with sort-based capacity dispatch.
+"""Mixture-of-Experts FFN: dropless, over the experts this chip holds.
 
-Routing runs independently per *routing group* (one group per data shard by
-default) so the dispatch buffers stay sharded over the data axis while the
-expert axis shards over "model" — the same rule PIMSAB's compiler applies:
-data-parallel loops map across tiles (data axis), reductions stay local.
+The router ranks all ``cfg.n_experts`` experts for every token.  The layer
+holds the experts ``[cfg.first_held_expert, + cfg.n_held_experts)`` (all of
+them unless the config says otherwise, as expert parallelism would divide
+them) and computes their part of the result, plus the shared experts, which
+every chip computes alike.  Nothing is dropped: every (token, held expert)
+pair the router picks is computed.
 
-Dispatch is gather/scatter-based (no (T, E, C) one-hot einsum): tokens are
-argsorted by expert id, their position within the expert segment is computed
-with a searchsorted, over-capacity tokens are dropped, and the kept tokens are
-scattered into an (E, C, D) buffer that feeds a batched expert matmul.
+Two dispatches, chosen by the number of tokens.  Up to ``TILE_ROWS``
+tokens (a decode step), every held expert runs on every token and each
+result is weighted by what the token gave that expert (0 if it did not
+choose it): one batched matmul that reads each held expert's weights once.
+Past that (a prefill), the (token, choice) pairs are sorted by held expert
+and walked in tiles of ``TILE_ROWS`` rows, each tile inside one expert's
+segment: a tile gathers its tokens, runs that expert's SwiGLU and
+scatter-adds the gated result.  That loop has a static worst-case count of
+tiles (every token on every held expert) and a ``lax.cond`` skips the tiles
+past those routing filled, so the work follows the tokens routed here and
+reverse-mode differentiation still works.  (Inside a loop the expert weights
+cannot be read in place: each layer's are copied out of the stacked
+parameters, which a decode step would pay for every layer.)  Either way an
+expert's SwiGLU is int8 when its weights are quantized, like every other
+linear.
+
+Routing runs per routing group (one per data shard by default): groups
+change where dispatch happens, not the result.
 """
 from __future__ import annotations
 
 import math
-from typing import Dict, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import Params, dense_init, swiglu
+from repro.models.common import Params, dense_init, swiglu_ffn, swiglu_init
+
+TILE_ROWS = 256
 
 
 def moe_init(key, cfg, dtype) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
-    ks = jax.random.split(key, 4)
-    return {
-        "router": {"w": dense_init(ks[0], d, e, jnp.float32)},
-        "w_gate": dense_init(ks[1], e * d, f, dtype).reshape(e, d, f),
-        "w_up": dense_init(ks[2], e * d, f, dtype).reshape(e, d, f),
-        "w_down": dense_init(ks[3], e * f, d, dtype).reshape(e, f, d),
-    }
+    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_held_experts
+    ks = jax.random.split(key, 5)
+
+    def experts(k, d_in, d_out):
+        w = jax.random.normal(k, (e, d_in, d_out), jnp.float32) / math.sqrt(d_in)
+        return {"w": w.astype(dtype)}
+
+    router: Params = {"w": dense_init(ks[0], d, cfg.n_experts, jnp.float32)}
+    if cfg.router == "sigmoid":
+        router["bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
+    p = {"router": router, "w_gate": experts(ks[1], d, f), "w_up": experts(ks[2], d, f),
+         "w_down": experts(ks[3], f, d)}
+    if cfg.n_shared_experts:
+        p["shared"] = swiglu_init(ks[4], d, f * cfg.n_shared_experts, dtype)
+    return p
 
 
-def _route_group(x: jnp.ndarray, logits: jnp.ndarray, k: int, capacity: int):
-    """Single routing group.  x: (T, D); logits: (T, E) fp32.
-
-    Returns (buf (E*C, D), combine info) for gather-based un-dispatch.
-    """
-    t, e = logits.shape
-    gates, eidx = jax.lax.top_k(logits, k)  # (T,k)
-    gates = jax.nn.softmax(gates, axis=-1)
-    flat_e = eidx.reshape(-1)  # (T*k,)
-    flat_t = jnp.repeat(jnp.arange(t), k)
-    flat_g = gates.reshape(-1)
-    order = jnp.argsort(flat_e, stable=True)
-    se, st, sg = flat_e[order], flat_t[order], flat_g[order]
-    # position of each routed token within its expert's segment
-    seg_start = jnp.searchsorted(se, jnp.arange(e), side="left")  # (E,)
-    pos = jnp.arange(t * k) - seg_start[se]
-    keep = pos < capacity
-    slot = jnp.where(keep, se * capacity + pos, e * capacity)  # overflow row
-    buf = jnp.zeros((e * capacity + 1, x.shape[-1]), x.dtype).at[slot].set(x[st])
-    return buf[: e * capacity], (slot, st, sg, keep)
+def route(p: Params, x: jnp.ndarray, cfg) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """x: (T, D).  Returns each token's ``experts_per_token`` expert ids
+    (T, k), their weights (T, k) float32, and the load-balance loss."""
+    logits = x.astype(jnp.float32) @ p["w"]  # (T, E)
+    k = cfg.experts_per_token
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(scores + p["bias"], k)  # the bias picks, it does not weigh
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20) * cfg.routed_scaling
+        return idx, w, jnp.float32(0)
+    top, idx = jax.lax.top_k(logits, k)
+    # Switch-style load-balance loss: router probability mass x top-1 dispatch mass
+    me = jnp.mean(jax.nn.softmax(logits, axis=-1), axis=0)
+    ce = jnp.mean(jax.nn.one_hot(jnp.argmax(logits, axis=-1), cfg.n_experts, dtype=jnp.float32), axis=0)
+    return idx, jax.nn.softmax(top, axis=-1), cfg.n_experts * jnp.sum(me * ce)
 
 
-def _combine_group(y: jnp.ndarray, info, t: int) -> jnp.ndarray:
-    """y: (E*C, D_out) expert outputs -> (T, D_out)."""
-    slot, st, sg, keep = info
-    contrib = y[jnp.where(keep, slot, 0)]
-    contrib = contrib * jnp.where(keep, sg, 0.0).astype(contrib.dtype)[:, None]
-    return jnp.zeros((t, y.shape[-1]), y.dtype).at[st].add(contrib)
+def held_experts_part(experts: Params, x: jnp.ndarray, idx: jnp.ndarray, w: jnp.ndarray,
+                      first: int, counted: Optional[jnp.ndarray] = None
+                      ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The held experts' part of the routed result, float32 (T, D), and how
+    many held experts at least one counted token chose (``counted``: (T,)
+    bool, every token if not given; it changes no result).  ``experts``
+    holds ``(held, d_in, d_out)`` SwiGLU weights for experts ``first ...``."""
+    t, d = x.shape
+    k = idx.shape[1]
+    held = jax.tree_util.tree_leaves(experts["w_down"])[0].shape[0]
+    local = idx - first
+    flat = jnp.where((local >= 0) & (local < held), local, held).reshape(-1)
+    counts = jnp.bincount(flat, length=held + 1)[:held]
+    if counted is None:
+        used = jnp.sum(counts > 0)
+    else:
+        mine = jnp.where(jnp.repeat(counted, k), flat, held)
+        used = jnp.sum(jnp.bincount(mine, length=held + 1)[:held] > 0)
+    if t <= TILE_ROWS:
+        gate = jnp.einsum("tk,tke->et", w, jax.nn.one_hot(local, held, dtype=w.dtype))
+        y = jax.vmap(swiglu_ffn, in_axes=(0, None))(experts, x)  # (held, t, d)
+        return jnp.einsum("et,etd->td", gate, y.astype(jnp.float32)), used
+    tile = TILE_ROWS
+    order = jnp.argsort(flat, stable=True)  # held experts' pairs first, by expert
+    tiles = -(-counts // tile)
+    tiles_end = jnp.cumsum(tiles)
+    seg_start = jnp.cumsum(counts) - counts
+    flat_w = w.reshape(-1)
+    rows = jnp.arange(tile)
+
+    def one_tile(i, out):
+        e = jnp.searchsorted(tiles_end, i, side="right")
+        r = (i - tiles_end[e] + tiles[e]) * tile + rows  # ranks inside expert e's segment
+        live = r < counts[e]
+        pair = order[jnp.minimum(seg_start[e] + r, t * k - 1)]
+        tok = jnp.where(live, pair // k, t)  # t: no token
+        pe = jax.tree_util.tree_map(lambda a: a[e], experts)
+        y = swiglu_ffn(pe, x.at[tok].get(mode="fill", fill_value=0))
+        gate = jnp.where(live, flat_w[pair], 0.0)
+        return out.at[tok].add(y.astype(jnp.float32) * gate[:, None], mode="drop")
+
+    def step(i, out):
+        return jax.lax.cond(i < tiles_end[-1], one_tile, lambda i, o: o, i, out)
+
+    most = -(-t * min(k, held) // tile) + held
+    out = jax.lax.fori_loop(0, most, step, jnp.zeros((t, d), jnp.float32))
+    return out, used
 
 
-def moe_ffn(p: Params, x: jnp.ndarray, cfg, n_groups: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x: (B, S, D) -> (out, aux_loss).  Routed per group of B*S/n_groups tokens."""
+def moe_ffn(p: Params, x: jnp.ndarray, cfg, n_groups: int,
+            counted: Optional[jnp.ndarray] = None):
+    """x: (B, S, D) -> (out, aux_loss, held experts chosen by a counted
+    token; ``counted`` (B, S) bool, every token if not given).  Routed per
+    group of B*S/n_groups tokens; ``route``, ``experts`` and ``shared``
+    name-scope the three parts."""
     b, s, d = x.shape
-    e, k = cfg.n_experts, cfg.experts_per_token
     tokens = b * s
     assert tokens % n_groups == 0, (tokens, n_groups)
-    tg = tokens // n_groups
-    capacity = max(k, int(math.ceil(tg * k / e * cfg.moe_capacity_factor)))
-    xg = x.reshape(n_groups, tg, d)
-    logits = (xg.astype(jnp.float32) @ p["router"]["w"])  # (G, Tg, E)
+    xg = x.reshape(n_groups, tokens // n_groups, d)
+    cg = None if counted is None else counted.reshape(n_groups, tokens // n_groups)
+    experts = {n: p[n] for n in ("w_gate", "w_up", "w_down")}
 
-    def per_group(xi, li):
-        buf, info = _route_group(xi, li, k, capacity)  # (E*C, D)
-        buf = buf.reshape(e, capacity, d)
-        gate = jnp.einsum("ecd,edf->ecf", buf, p["w_gate"])
-        up = jnp.einsum("ecd,edf->ecf", buf, p["w_up"])
-        act = swiglu(gate, up)
-        down = jnp.einsum("ecf,efd->ecd", act, p["w_down"])
-        return _combine_group(down.reshape(e * capacity, d), info, tg)
+    def group(xc):
+        xi, ci = xc
+        with jax.named_scope("route"):
+            idx, w, aux = route(p["router"], xi, cfg)
+        with jax.named_scope("experts"):
+            y, used = held_experts_part(experts, xi, idx, w, cfg.first_held_expert, ci)
+        return y, aux, used
 
-    out = jax.vmap(per_group)(xg, logits)
-    # Switch-style load-balance aux loss
-    probs = jax.nn.softmax(logits, axis=-1)  # (G, Tg, E)
-    me = jnp.mean(probs, axis=1)  # (G, E) router prob mass
-    top1 = jnp.argmax(logits, axis=-1)
-    ce = jnp.mean(jax.nn.one_hot(top1, e, dtype=jnp.float32), axis=1)  # (G, E) dispatch mass
-    aux = e * jnp.mean(jnp.sum(me * ce, axis=-1))
-    return out.reshape(b, s, d), aux
+    if n_groups == 1:  # a vmap would turn each tile's cond into a select of both branches
+        y, aux, used = group((xg[0], None if cg is None else cg[0]))
+    else:
+        y, aux, used = jax.lax.map(group, (xg, cg))
+        aux, used = jnp.mean(aux), jnp.sum(used)
+    y = y.reshape(b, s, d)
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            y = y + swiglu_ffn(p["shared"], x).astype(jnp.float32)
+    return y.astype(x.dtype), aux, used

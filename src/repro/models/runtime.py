@@ -16,6 +16,7 @@ class RunFlags:
     attn_chunk: int = 1024          # kv/q chunk for flash-style attention
     triangular_attn: bool = True    # causal chunk scheduling (skip j>i chunks)
     flash_threshold: int = 2048     # seqs longer than this use chunked attention
+    prefill_block: int = 0          # lanes a prefill runs at a time (0: all)
     # memory
     remat: bool = True              # checkpoint each block in train mode
     grad_accum: int = 1             # microbatches per step (activation memory / k)

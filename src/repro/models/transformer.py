@@ -1,22 +1,36 @@
 """Composable transformer: one model assembly covering all 10 assigned
-architectures (dense GQA, MoE, RG-LRU hybrid, xLSTM, enc-dec audio, VLM).
+architectures (dense GQA, latent attention with MoE, RG-LRU hybrid, xLSTM,
+enc-dec audio, VLM).
 
 The layer stack is the config's ``block_pattern`` tiled to ``n_layers`` and
 executed as ``lax.scan`` over *pattern groups* (params stacked on a leading
-group axis) so the HLO stays depth-independent.  Three entry points:
+group axis) so the HLO stays depth-independent.  A model with leading dense
+layers (``first_dense_layers``, DeepSeek-V3's ``first_k_dense_replace``)
+keeps them in a stack of their own, ``dense_blocks``, scanned before
+``blocks``; parameters and cache mirror the two.  Three entry points:
 
 * ``forward``     — full-sequence logits (training / evaluation).
 * ``prefill``     — full-sequence forward that also returns the decode cache.
 * ``decode_step`` — one token in, one token out, cache updated in place.
 
 Each block's attention and feed-forward run under the ``jax.named_scope``
-``attn`` and ``ffn``, the logits under ``lm_head``, and the decode step's
-cache update under ``attn/kv_write``: a profiler trace's device ops carry
-these names, so a step's device time splits by part (ops outside them, such
-as norms and the scan's stacking of the cache, carry none).
+``attn`` and ``ffn`` (``moe`` for an expert layer, with ``moe/route``,
+``moe/experts`` and ``moe/shared`` inside), the logits under ``lm_head``,
+and the decode step's cache update under ``attn/kv_write``; latent attention
+adds ``attn/mla_expand`` (sequence form) and ``attn/mla_absorb`` (decode).
+A profiler trace's device ops carry these names, so a step's device time
+splits by part (ops outside them, such as norms and the scan's stacking of
+the cache, carry none).
+
+A MoE model's decode cache also counts, on the device, the held experts
+that at least one live lane's token chose, summed over its expert layers and
+decode steps since the prefill (``expert_slots_used``); ``live_lanes``
+(B,) bool, all true after the prefill, says which lanes' tokens count (a
+server sets a retired lane's false: the pad token it decodes serves no one).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
@@ -42,8 +56,10 @@ from repro.models.common import (
     rmsnorm,
     rmsnorm_init,
     softmax_cross_entropy,
-    swiglu,
+    swiglu_ffn,
+    swiglu_init,
 )
+from repro.models import mla
 from repro.models.moe import moe_ffn, moe_init
 from repro.models.recurrent import (
     CONV_K,
@@ -79,22 +95,15 @@ def _attn_init(key, cfg, dtype, cross: bool = False) -> Params:
     return p
 
 
-def _ffn_init(key, cfg, dtype) -> Params:
-    d, f = cfg.d_model, cfg.d_ff
-    ks = jax.random.split(key, 3)
-    return {
-        "w_gate": linear_init(ks[0], d, f, dtype),
-        "w_up": linear_init(ks[1], d, f, dtype),
-        "w_down": linear_init(ks[2], f, d, dtype),
-    }
-
-
-def _block_init(key, cfg, kind: str, dtype, decoder: bool) -> Params:
-    """One block = norm + temporal mixer (+ cross-attn) (+ norm + FFN)."""
+def _block_init(key, cfg, kind: str, dtype, decoder: bool, dense: bool = False) -> Params:
+    """One block = norm + temporal mixer (+ cross-attn) (+ norm + FFN); a
+    ``dense`` block of a MoE model has a plain MLP of ``dense_d_ff``."""
     ks = jax.random.split(key, 4)
     p: Params = {"ln1": rmsnorm_init(cfg.d_model, dtype)}
     if kind in ("attn", "local_attn"):
         p["attn"] = _attn_init(ks[0], cfg, dtype)
+    elif kind == "mla":
+        p["attn"] = mla.mla_init(ks[0], cfg, dtype)
     elif kind == "rglru":
         from repro.models.recurrent import rglru_block_init
 
@@ -112,20 +121,46 @@ def _block_init(key, cfg, kind: str, dtype, decoder: bool) -> Params:
     if decoder and cfg.is_encdec:
         p["lnx"] = rmsnorm_init(cfg.d_model, dtype)
         p["cross"] = _attn_init(ks[1], cfg, dtype)
-    if cfg.d_ff > 0 and kind in ("attn", "local_attn", "rglru"):
+    if cfg.d_ff > 0 and kind in ("attn", "local_attn", "rglru", "mla"):
         p["ln2"] = rmsnorm_init(cfg.d_model, dtype)
-        p["ffn"] = moe_init(ks[2], cfg, dtype) if cfg.is_moe else _ffn_init(ks[2], cfg, dtype)
+        if dense:
+            p["ffn"] = swiglu_init(ks[2], cfg.d_model, cfg.dense_d_ff, dtype)
+        elif cfg.is_moe:
+            p["ffn"] = moe_init(ks[2], cfg, dtype)
+        else:
+            p["ffn"] = swiglu_init(ks[2], cfg.d_model, cfg.d_ff, dtype)
     return p
 
 
-def _stack_groups(key, cfg, dtype, n_groups: int, pattern, decoder: bool) -> Params:
+def _stacks(cfg) -> Tuple[Tuple[str, Tuple[str, ...], int], ...]:
+    """The layer stacks in order: (params/cache key, pattern, groups)."""
+    tiled = ("blocks", cfg.block_pattern, cfg.pattern_groups())
+    if cfg.first_dense_layers:
+        return (("dense_blocks", cfg.block_pattern[:1], cfg.first_dense_layers), tiled)
+    return (tiled,)
+
+
+def _scan_stack(body, carry, xs, flags: RunFlags, groups: int):
+    """``lax.scan`` of ``body`` over a stack's groups, or the same unrolled
+    (``flags.scan_layers`` off: cost-analysis correction, experiments)."""
+    if flags.scan_layers:
+        return jax.lax.scan(body, carry, xs)
+    ys = []
+    for gi in range(groups):
+        carry, y = body(carry, jax.tree_util.tree_map(lambda l: l[gi], xs))
+        ys.append(y)
+    return carry, jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *ys)
+
+
+def _stack_groups(key, cfg, dtype, n_groups: int, pattern, decoder: bool,
+                  dense: bool = False) -> Params:
     """Init per group then stack leaves on a leading (G, ...) axis."""
     gkeys = jax.random.split(key, n_groups)
 
     def one_group(k):
         pk = jax.random.split(k, len(pattern))
         return {
-            f"{i:02d}_{kind}": _block_init(pk[i], cfg, kind, dtype, decoder)
+            f"{i:02d}_{kind}": _block_init(pk[i], cfg, kind, dtype, decoder, dense)
             for i, kind in enumerate(pattern)
         }
 
@@ -154,6 +189,11 @@ def init_params(key: jax.Array, cfg: ModelConfig) -> Params:
         params["audio_adapter"] = frontend.audio_adapter_init(ks[4], cfg, dtype)
     if cfg.frontend == "vision":
         params["vision_adapter"] = frontend.vision_adapter_init(ks[5], cfg, dtype)
+    if cfg.first_dense_layers:
+        params["dense_blocks"] = _stack_groups(
+            ks[6], cfg, dtype, cfg.first_dense_layers, cfg.block_pattern[:1], decoder=True,
+            dense=True,
+        )
     return params
 
 
@@ -241,14 +281,20 @@ def _cross_kv(p: Params, enc_out: jnp.ndarray, cfg) -> Dict[str, jnp.ndarray]:
     }
 
 
-def _ffn_apply(p: Params, x: jnp.ndarray, cfg, flags: RunFlags, rules) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    if cfg.is_moe:
+def _ffn_apply(p: Params, x: jnp.ndarray, cfg, flags: RunFlags, rules, counted=None):
+    """Returns (y, aux_loss, held experts a ``counted`` token chose); an
+    expert layer is one whose parameters hold a router."""
+    if "router" in p:
         groups = flags.routing_groups or (rules.dp if rules is not None else 1)
         tokens = x.shape[0] * x.shape[1]
         while tokens % groups:
             groups -= 1
-        return moe_ffn(p, x, cfg, groups)
-    return linear(p["w_down"], swiglu(linear(p["w_gate"], x), linear(p["w_up"], x))), jnp.float32(0)
+        return moe_ffn(p, x, cfg, groups, counted)
+    return swiglu_ffn(p, x), jnp.float32(0), jnp.int32(0)
+
+
+def _ffn_scope(p: Params) -> str:
+    return "moe" if "router" in p else "ffn"
 
 
 def _block_apply_seq(
@@ -271,6 +317,10 @@ def _block_apply_seq(
         with jax.named_scope("attn"):
             y, kv = _attn_apply(p["attn"], h, cfg, flags, positions, kind, causal)
         cache_out.update(kv)
+    elif kind == "mla":
+        with jax.named_scope("attn"):
+            y, kv = mla.mla_attention(p["attn"], h, cfg, flags, positions)
+        cache_out.update(kv)
     elif kind == "rglru":
         y, st = rglru_block_apply(p["mixer"], h, cfg, states)
         cache_out.update(st)
@@ -288,8 +338,8 @@ def _block_apply_seq(
         cache_out["cross_k"], cache_out["cross_v"] = kvx["k"], kvx["v"]
     if "ffn" in p:
         h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-        with jax.named_scope("ffn"):
-            y2, a = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
+        with jax.named_scope(_ffn_scope(p["ffn"])):
+            y2, a, _ = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
         x = x + y2
         aux = aux + a
     return x, cache_out, aux
@@ -302,6 +352,8 @@ def _block_apply_seq(
 
 def _embed_tokens(params: Params, tokens: jnp.ndarray, cfg) -> jnp.ndarray:
     x = params["embed"]["w"][tokens]
+    if not cfg.embed_scale:
+        return x
     return x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
 
 
@@ -343,7 +395,6 @@ def forward(
     if cfg.is_encdec:
         enc_out = _run_encoder(params, cfg, flags, rules, batch["enc_embeds"])
     positions = jnp.arange(s)[None]
-    pattern = cfg.block_pattern
 
     def one_block(pb, xx, pos_arg, enc_arg, kind):
         out, _, a = _block_apply_seq(
@@ -356,10 +407,10 @@ def forward(
     # intermediates live in the backward at once.
     blocked = {
         kind: (jax.checkpoint(partial(one_block, kind=kind)) if flags.remat else partial(one_block, kind=kind))
-        for kind in set(pattern)
+        for kind in set(cfg.block_pattern)
     }
 
-    def group_body(carry, gp):
+    def group_body(carry, gp, pattern):
         x, aux = carry
         for i, kind in enumerate(pattern):
             x, a = blocked[kind](gp[f"{i:02d}_{kind}"], x, positions, enc_out)
@@ -367,15 +418,10 @@ def forward(
         x = constrain(x, rules, act_spec(b, rules) if rules else None)
         return (x, aux), None
 
-    if flags.scan_layers:
-        (x, aux), _ = jax.lax.scan(group_body, (x, jnp.float32(0)), params["blocks"])
-    else:
-        carry = (x, jnp.float32(0))
-        g = cfg.pattern_groups()
-        for gi in range(g):
-            gp = jax.tree_util.tree_map(lambda l: l[gi], params["blocks"])
-            carry, _ = group_body(carry, gp)
-        x, aux = carry
+    carry = (x, jnp.float32(0))
+    for key, pattern, groups in _stacks(cfg):
+        carry, _ = _scan_stack(partial(group_body, pattern=pattern), carry, params[key], flags, groups)
+    x, aux = carry
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = _lm_head(params, x, cfg)
     return logits, aux
@@ -418,6 +464,10 @@ def _cache_entry_shape(cfg, kind: str, batch: int, max_len: int, flags=DEFAULT_F
 
     if kind == "attn":
         entry = kv_entry(max_len)
+    elif kind == "mla":
+        if flags.quant_kv:
+            raise ValueError("latent attention keeps a bfloat16 latent cache (quant_kv unsupported)")
+        entry = mla.cache_entry(cfg, batch, max_len, dt)
     elif kind == "local_attn":
         entry = kv_entry(min(cfg.window, max_len))
     elif kind == "rglru":
@@ -436,19 +486,21 @@ def _cache_entry_shape(cfg, kind: str, batch: int, max_len: int, flags=DEFAULT_F
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int, flags: RunFlags = DEFAULT_FLAGS) -> Params:
-    """Decode cache: stacked (G, ...) per pattern position + position scalar."""
+    """Decode cache: per stack, stacked (G, B, ...) per pattern position; the
+    position scalar; for a MoE model the count of held experts chosen and
+    the lanes whose tokens it counts."""
 
-    def stacked(kind):
-        g = cfg.pattern_groups()
+    def stacked(kind, g):
         entry = _cache_entry_shape(cfg, kind, batch, max_len, flags)
         return jax.tree_util.tree_map(lambda l: jnp.broadcast_to(l, (g,) + l.shape), entry)
 
-    return {
-        "pos": jnp.zeros((), jnp.int32),
-        "blocks": {
-            f"{i:02d}_{kind}": stacked(kind) for i, kind in enumerate(cfg.block_pattern)
-        },
-    }
+    cache = {"pos": jnp.zeros((), jnp.int32)}
+    for key, pattern, groups in _stacks(cfg):
+        cache[key] = {f"{i:02d}_{kind}": stacked(kind, groups) for i, kind in enumerate(pattern)}
+    if cfg.is_moe:
+        cache["expert_slots_used"] = jnp.zeros((), jnp.int32)
+        cache["live_lanes"] = jnp.ones((batch,), bool)
+    return cache
 
 
 def cache_shape(cfg: ModelConfig, batch: int, max_len: int, flags: RunFlags = DEFAULT_FLAGS) -> Params:
@@ -468,10 +520,17 @@ def prefill(
     rules: Optional[MeshRules] = None,
     max_len: Optional[int] = None,
 ) -> Tuple[Params, jnp.ndarray]:
-    """Run the prompt, return (cache, last-token logits)."""
+    """Run the prompt, return (cache, last-token logits).
+
+    With ``flags.prefill_block`` (lanes) below the batch, the prompts run a
+    block of lanes at a time, each block through every layer, into one cache:
+    the peak memory is one block's activations."""
     tokens = batch["tokens"]
     b, s = tokens.shape
     max_len = max_len or s
+    blk = flags.prefill_block
+    if blk and blk < b:
+        return _prefill_blocked(params, cfg, batch, flags, rules, max_len, blk)
     x = _embed_tokens(params, tokens, cfg)
     if cfg.frontend == "vision" and "patch_embeds" in batch:
         x = frontend.fuse_patches(params["vision_adapter"], x, batch["patch_embeds"])
@@ -480,9 +539,8 @@ def prefill(
     if cfg.is_encdec:
         enc_out = _run_encoder(params, cfg, flags, rules, batch["enc_embeds"])
     positions = jnp.arange(s)[None]
-    pattern = cfg.block_pattern
 
-    def group_body(x, gp):
+    def group_body(x, gp, pattern):
         entries = {}
         for i, kind in enumerate(pattern):
             x, cache_new, _ = _block_apply_seq(
@@ -494,19 +552,38 @@ def prefill(
         x = constrain(x, rules, act_spec(b, rules) if rules else None)
         return x, entries
 
-    if flags.scan_layers:
-        x, stacked_entries = jax.lax.scan(group_body, x, params["blocks"])
-    else:  # unrolled (cost-analysis correction path / perf experiments)
-        entries_list = []
-        for gi in range(cfg.pattern_groups()):
-            gp = jax.tree_util.tree_map(lambda l: l[gi], params["blocks"])
-            x, e = group_body(x, gp)
-            entries_list.append(e)
-        stacked_entries = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *entries_list)
+    stacks = {}
+    for key, pattern, groups in _stacks(cfg):
+        x, stacks[key] = _scan_stack(partial(group_body, pattern=pattern), x, params[key], flags, groups)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = _lm_head(params, x[:, -1:], cfg)[:, 0]
-    cache = {"pos": jnp.asarray(s, jnp.int32), "blocks": stacked_entries}
+    cache = {"pos": jnp.asarray(s, jnp.int32), **stacks}
+    if cfg.is_moe:
+        cache["expert_slots_used"] = jnp.zeros((), jnp.int32)
+        cache["live_lanes"] = jnp.ones((b,), bool)
     return cache, logits
+
+
+def _prefill_blocked(params, cfg, batch, flags, rules, max_len: int, blk: int):
+    """``prefill`` a block of ``blk`` lanes at a time, into one cache."""
+    b, s = batch["tokens"].shape
+    if b % blk:
+        raise ValueError(f"prefill_block={blk} does not divide the batch of {b}")
+    unblocked = dataclasses.replace(flags, prefill_block=0)
+
+    def block(cache, i):
+        lanes = {k: jax.lax.dynamic_slice_in_dim(v, i * blk, blk, axis=0) for k, v in batch.items()}
+        part, logits = prefill(params, cfg, lanes, unblocked, rules, max_len)
+        for key, _, _ in _stacks(cfg):  # lanes sit on axis 1, after the group axis
+            cache[key] = jax.tree_util.tree_map(
+                lambda full, new: jax.lax.dynamic_update_slice_in_dim(
+                    full, new.astype(full.dtype), i * blk, axis=1),
+                cache[key], part[key])
+        return cache, logits
+
+    cache, logits = jax.lax.scan(block, init_cache(cfg, b, max_len, unblocked), jnp.arange(b // blk))
+    cache["pos"] = jnp.asarray(s, jnp.int32)
+    return cache, logits.reshape(b, -1)
 
 
 def _seq_cache_to_decode_cache(
@@ -527,6 +604,8 @@ def _seq_cache_to_decode_cache(
                 out[n] = kv_dict[n]
         return out
 
+    if kind == "mla":
+        return {n: jnp.pad(entries[n], ((0, 0), (0, max_len - s), (0, 0))) for n in ("c_kv", "k_pe")}
     if kind == "attn":
         out = {}
         for n in ("k", "v"):
@@ -612,11 +691,12 @@ def decode_step(
     b = tokens.shape[0]
     pos = cache["pos"]
     x = _embed_tokens(params, tokens, cfg)
-    pattern = cfg.block_pattern
+    live = cache["live_lanes"][:, None] if cfg.is_moe else None
 
-    def group_body(x, scan_in):
+    def group_body(x, scan_in, pattern):
         gp, gcache = scan_in
         new_entries = {}
+        used = jnp.int32(0)
         for i, kind in enumerate(pattern):
             key = f"{i:02d}_{kind}"
             p, entry = gp[key], gcache[key]
@@ -624,6 +704,9 @@ def decode_step(
             if kind in ("attn", "local_attn"):
                 with jax.named_scope("attn"):
                     y, new_entry = _attn_decode(p["attn"], h, cfg, entry, pos, kind, rules)
+            elif kind == "mla":
+                with jax.named_scope("attn"):
+                    y, new_entry = mla.mla_decode(p["attn"], h, cfg, entry, pos)
             elif kind == "rglru":
                 y, st = rglru_block_apply(p["mixer"], h, cfg, entry)
                 new_entry = st
@@ -643,23 +726,25 @@ def decode_step(
                 new_entry["cross_k"], new_entry["cross_v"] = entry["cross_k"], entry["cross_v"]
             if "ffn" in p:
                 h2 = rmsnorm(p["ln2"], x, cfg.norm_eps)
-                with jax.named_scope("ffn"):
-                    y2, _ = _ffn_apply(p["ffn"], h2, cfg, flags, rules)
+                with jax.named_scope(_ffn_scope(p["ffn"])):
+                    y2, _, u = _ffn_apply(p["ffn"], h2, cfg, flags, rules, live)
                 x = x + y2
+                if cfg.is_moe:
+                    used = used + u
             new_entries[key] = new_entry
-        return x, new_entries
+        return x, ((new_entries, used) if cfg.is_moe else new_entries)
 
-    if flags.scan_layers:
-        x, new_blocks = jax.lax.scan(group_body, x, (params["blocks"], cache["blocks"]))
-    else:
-        blocks_list = []
-        for gi in range(cfg.pattern_groups()):
-            gp = jax.tree_util.tree_map(lambda l: l[gi], params["blocks"])
-            gc = jax.tree_util.tree_map(lambda l: l[gi], cache["blocks"])
-            x, nb = group_body(x, (gp, gc))
-            blocks_list.append(nb)
-        new_blocks = jax.tree_util.tree_map(lambda *ls: jnp.stack(ls), *blocks_list)
+    stacks, used = {}, jnp.int32(0)
+    for key, pattern, groups in _stacks(cfg):
+        x, stacks[key] = _scan_stack(partial(group_body, pattern=pattern), x,
+                                     (params[key], cache[key]), flags, groups)
+        if cfg.is_moe:
+            stacks[key], u = stacks[key]
+            used = used + jnp.sum(u)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = _lm_head(params, x, cfg)[:, 0]
-    new_cache = {"pos": pos + 1, "blocks": new_blocks}
+    new_cache = {"pos": pos + 1, **stacks}
+    if cfg.is_moe:
+        new_cache["expert_slots_used"] = cache["expert_slots_used"] + used
+        new_cache["live_lanes"] = cache["live_lanes"]
     return new_cache, logits

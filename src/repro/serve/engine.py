@@ -68,10 +68,10 @@ def cache_specs(cfg: ModelConfig, batch: int, max_len: int, rules: MeshRules, fl
         inner = cache_entry_spec(leaf.shape[1:], cfg, rules, seq_shard_kv=flags.seq_shard_kv)
         return P(None, *inner)
 
-    return {
-        "pos": P(),
-        "blocks": jax.tree_util.tree_map_with_path(visit, shapes["blocks"]),
-    }
+    # every stack (``dense_blocks`` too); the position and a MoE model's
+    # counter and lane mask are replicated
+    return {k: jax.tree_util.tree_map_with_path(visit, v) if isinstance(v, dict) else P()
+            for k, v in shapes.items()}
 
 
 def make_prefill_step(cfg, flags=DEFAULT_FLAGS, rules=None, max_len=None, backend=None) -> Callable:
@@ -113,11 +113,23 @@ class ServeCounters:
     lanes whose request still wanted a token.  ``useful_lane_steps /
     lane_steps`` is the share of decode work that served a request: a
     lock-step batch keeps decoding its retired lanes until its longest
-    request ends.  Take differences of two readings to count an interval."""
+    request ends.  Take differences of two readings to count an interval.
+
+    For a model with experts, ``expert_slots`` counts the held experts of
+    every expert layer in each decode step (held experts x expert layers x
+    decode steps), ``expert_slots_used`` those of them that the token of at
+    least one lane whose request still wanted a token chose (counted on the
+    device, read once per ``run``; a retired lane's pad token counts for
+    nothing); their ratio is the share of held-expert weight reads that
+    served a request.  ``expert_slots_by_run`` keeps ``(expert_slots,
+    expert_slots_used)`` of each run, in order."""
 
     decode_steps: int = 0
     lane_steps: int = 0
     useful_lane_steps: int = 0
+    expert_slots: int = 0
+    expert_slots_used: int = 0
+    expert_slots_by_run: List[Tuple[int, int]] = field(default_factory=list)
 
 
 class ServeEngine:
@@ -186,7 +198,8 @@ class ServeEngine:
         ``serve.pack`` builds the prefill batch; ``serve.prefill`` dispatches
         the prefill step; per decode step, ``serve.retire`` hands each lane's
         token to its request and retires finished lanes, ``serve.decode``
-        uploads the tokens and dispatches the decode step, and
+        uploads the tokens (for a model with experts, after a lane retires,
+        the live lanes too) and dispatches the decode step, and
         ``serve.sample`` picks the next tokens and waits for them on the
         host (after the prefill too).  Device time that no op fills while
         the host is inside one of them is that phase's cost to the chip."""
@@ -199,6 +212,7 @@ class ServeEngine:
                 next_tok = self._greedy(logits)
             steps = max(r.max_new_tokens for r in requests)
             before = [len(r.generated) for r in requests]
+            live = np.ones(len(requests), bool)  # as the prefill's cache has it
             decode_steps = 0
             for _ in range(steps):
                 with TraceAnnotation("serve.retire"):
@@ -215,6 +229,11 @@ class ServeEngine:
                     if all(r.done for r in requests):
                         break
                 with TraceAnnotation("serve.decode"):
+                    if self.cfg.is_moe:  # the expert counter counts the live lanes' tokens
+                        now = np.array([not r.done for r in requests])
+                        if (now != live).any():
+                            live = now
+                            cache = dict(cache, live_lanes=jnp.asarray(live))
                     cache, logits = self.decode_step(self.params, cache, jnp.asarray(next_tok)[:, None])
                 with TraceAnnotation("serve.sample"):
                     next_tok = self._greedy(logits)
@@ -225,4 +244,10 @@ class ServeEngine:
         c.lane_steps += len(requests) * decode_steps
         c.useful_lane_steps += sum(max(len(r.generated) - n - 1, 0)
                                    for r, n in zip(requests, before))
+        if self.cfg.is_moe:
+            slots = self.cfg.n_held_experts * self.cfg.moe_layers * decode_steps
+            used = int(cache["expert_slots_used"])
+            c.expert_slots += slots
+            c.expert_slots_used += used
+            c.expert_slots_by_run.append((slots, used))
         return requests

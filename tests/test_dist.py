@@ -54,10 +54,36 @@ def test_param_specs_moe_and_dense():
     cfg = get_config("kimi-k2-1t-a32b")  # 64 heads, 384 experts: divisible
     shapes = params_shape(cfg)
     specs = param_specs(shapes, cfg, rules)
-    blk = specs["blocks"]["00_attn"]
-    assert blk["attn"]["wq"]["w"] == P(None, None, "model")
-    assert blk["ffn"]["w_gate"] == P(None, "model", None, None)  # (G, E, d, f)
+    blk = specs["blocks"]["00_mla"]
+    # latent attention: heads column-parallel out of the latents, which replicate
+    assert blk["attn"]["wq_b"]["w"] == P(None, None, "model")
+    assert blk["attn"]["wkv_b"]["w"] == P(None, None, "model")
+    assert blk["attn"]["wq_a"]["w"] == P(None, None, None)
+    assert blk["attn"]["wo"]["w"] == P(None, "model", None)
+    assert blk["ffn"]["w_gate"]["w"] == P(None, "model", None, None)  # (G, E, d, f)
+    assert blk["ffn"]["shared"]["w_down"]["w"] == P(None, "model", None)
+    assert blk["ffn"]["router"]["w"] == P(None, None, None)
+    # the leading dense layer: a plain MLP, d_ff column-parallel
+    dense = specs["dense_blocks"]["00_mla"]
+    assert dense["ffn"]["w_gate"]["w"] == P(None, None, "model")
     assert specs["embed"]["w"] == P("model", None)
+
+
+@pytest.mark.parametrize("arch", ["qwen2-0.5b", "kimi-k2-1t-a32b"])
+def test_cache_specs_cover_every_cache_leaf(arch):
+    """A spec for every leaf of the decode cache: Kimi's dense-layer stack,
+    its expert counter and live-lane mask too."""
+    from repro.configs import reduced_config
+    from repro.models.transformer import cache_shape
+    from repro.serve.engine import cache_specs
+
+    cfg = reduced_config(get_config(arch))
+    shapes = cache_shape(cfg, 32, 64)
+    specs = cache_specs(cfg, 32, 64, _rules())
+    paired = jax.tree_util.tree_map(lambda sh, sp: (sh.ndim, sp), shapes, specs)
+    assert paired["pos"] == (0, P())
+    if cfg.is_moe:
+        assert set(specs) == {"pos", "blocks", "dense_blocks", "expert_slots_used", "live_lanes"}
 
 
 def test_batch_axis_fallbacks():

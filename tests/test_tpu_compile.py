@@ -100,3 +100,30 @@ def test_qwen2_decode_step_compiles_for_v5e(one_chip):
     step = make_decode_step(cfg, flags, backend="pallas")
     compiled = jax.jit(step).lower(params, cache, tokens).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_kimi_decode_step_compiles_for_v5e(one_chip):
+    """One chip's share of Kimi-K2 at published widths (the benchmark's
+    configuration): the absorbed-MLA decode step over a batch of 32 with an
+    8,704-position latent cache fits the chip."""
+    import json
+
+    from chipbench.harness import BENCH, load_module
+    from repro.models.runtime import RunFlags
+    from repro.models.transformer import cache_shape
+    from repro.serve.engine import make_decode_step
+
+    kimi = load_module(BENCH / "configs" / "kimi-k2.py")
+    cfgj = json.loads((BENCH / "configs" / "kimi-k2.json").read_text())
+    mix = json.loads((BENCH / "traffic" / "agent-8k.json").read_text())
+    cfg, flags = kimi.program_config(cfgj), RunFlags(**cfgj["run_flags"])
+    params, cache = jax.tree_util.tree_map(
+        lambda a: _struct(a, one_chip),
+        (jax.eval_shape(lambda: kimi.make_params(cfgj, 0)),
+         cache_shape(cfg, mix["batch"], mix["max_len"], flags)),
+    )
+    tokens = jax.ShapeDtypeStruct((mix["batch"], 1), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(make_decode_step(cfg, flags, backend="pallas")).lower(
+        params, cache, tokens).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes < 16e9

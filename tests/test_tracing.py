@@ -98,6 +98,25 @@ def test_decode_step_scopes_its_parts(engine):
         assert scope in text, scope
 
 
+def test_latent_attention_and_expert_steps_scope_their_parts():
+    """Kimi's steps: the expanded and absorbed forms of latent attention,
+    the cache write, the three parts of an expert layer and the dense
+    layer's ``ffn``."""
+    from repro.serve.engine import make_prefill_step
+
+    cfg = reduced_config(get_config("kimi-k2-1t-a32b"))
+    eng = ServeEngine(cfg, init_params(jax.random.key(0), cfg), FLAGS, max_len=32)
+    batch = eng.pack(requests([2, 2]))
+    text = jax.jit(make_prefill_step(cfg, FLAGS, max_len=32)).lower(eng.params, batch).compile()
+    assert "/attn/mla_expand/" in text.as_text()
+    cache, _ = eng.prefill_step(eng.params, batch)
+    step = jax.jit(make_decode_step(cfg, FLAGS))
+    text = step.lower(eng.params, cache, np.zeros((2, 1), np.int32)).compile().as_text()
+    for scope in ("/attn/mla_absorb/", "/attn/kv_write/", "/moe/route/", "/moe/experts/",
+                  "/moe/shared/", "/ffn/", "/lm_head/"):
+        assert scope in text, scope
+
+
 def _spans(log_dir):
     """Host spans ``(name, start, end)`` and the executables the device ran,
     from the one xplane file under ``log_dir``."""
