@@ -14,16 +14,17 @@ the paper's architecture (``repro.kernels.pimsab_backend``):
   (rows, KH·KW·C) operand of the MXU (path ``taps``).  A channel count too
   narrow to feed the MXU per tap (the RGB stem) takes a channels-last int8
   patch slab of KH·KW static slices as one tap (path ``patches``).
-  :func:`path_counts` counts the paths traced.  The pimsab backend still
+  ``tiling.path_counts`` counts the paths traced.  The pimsab backend still
   lowers through ``ref.im2col``, the §V-A layout contract, onto the ``mac``
   gemm pipeline.
 * ``int_matmul``  — raw-integer (M, K) × (K, N) with int32 accumulation: the
   network-head matmul whose activations arrive as another kernel's integer
   output; the kernel slices them itself.
-* ``maxpool2d`` / ``avgpool2d`` / ``global_avgpool`` — window reductions over
-  the ``ref.pool_patches`` window matrix; pimsab folds max via CmpGE +
+* ``maxpool2d`` / ``avgpool2d`` / ``global_avgpool`` — window reductions of
+  the channels-last input inside the kernel, over its KH·KW strided views:
+  no window matrix (path ``channels_last``).  pimsab folds max via CmpGE +
   masked copy and average via the constant-operand MAC plus a shift-read
-  divide.
+  divide, over the ``ref.pool_patches`` window matrix.
 
 ``x_bits`` / ``w_bits`` are *static precision hints*: the pimsab lowering
 sizes its fields from them (program mode cannot calibrate precision from
@@ -40,53 +41,38 @@ digit, a row block (bm, C ≤ 512) int8 and the next hb rows its taps reach
 8-bit weights; the output block; and the (bm, T·C) operand of one digit,
 about 1.2 MB: by these sizes some 6 MB of VMEM with double buffering.
 
-The pools transpose the window matrix to (K, P), lay P out as rows × 128
-lanes (``tiling.lane_rows``) and block the rows; the K window slabs of one
-block sit in VMEM together.
+Between the kernels an activation is channels-last: the conv's wrapper
+transposes its input to NHWC and its output back, and relu, the residual
+add and the pools map over the rows ``(N·H, W·C)`` of the same NHWC array
+(``tiling.nhwc_rows``).  Each wrapper keeps the NCHW contract at its edges,
+and inside the Program's one jit a wrapper's closing transpose meets the
+next one's opening transpose, which XLA folds away.  The conv's row width
+Wq is a whole 8-row tile (:func:`_tile_rows`), so that the NHWC arrays it
+pads and crops share one layout with the kernel's ``(rows, C)`` operands.  A pool's grid step holds
+whole images, about ``block`` × 128 input elements: their H rows first reduce
+over the window rows ``s·i + ky``, read as KH strided views of one 128-lane
+column at a time (Mosaic reads strided rows from a 128-lane ref only), then
+over the KW lane slices ``(s·j + kx)·C``, C wide.  The global pool is the
+window pool whose one window is the whole map.
 """
 from __future__ import annotations
 
 import functools
-from collections import Counter
-from typing import Dict, List, Optional, Tuple
+import math
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 from repro.kernels.api import active_pairs, register_kernel
 from repro.kernels.bitslice_matmul import ACC_BITS, SLICE_BITS, slices_for_bits, wide_matmul
-from repro.kernels.tiling import LANES, SUBLANES, fit_block, lane_rows, load32, pad_to, round_up
-
-
-def _pool_max_kernel(p_ref, o_ref):
-    o_ref[...] = jnp.max(load32(p_ref), axis=0).astype(o_ref.dtype)
-
-
-def _pool_sum_kernel(p_ref, o_ref, *, acc_dtype):
-    o_ref[...] = jnp.sum(p_ref[...].astype(acc_dtype), axis=0)
-
-
-def _blocked_pool(kernel, patches: jnp.ndarray, out_dtype, block: int, interpret: bool):
-    """Reduce the (P, K) window matrix over K.  The window axis leads, so
-    each grid step reduces K (block × 128) slabs elementwise on the VPU."""
-    p, k = patches.shape
-    slabs, br = lane_rows(patches.T, block)          # (K, R, 128)
-    r = slabs.shape[1]
-    out = pl.pallas_call(
-        kernel,
-        grid=(r // br,),
-        in_specs=[pl.BlockSpec((k, br, LANES), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((br, LANES), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((r, LANES), out_dtype),
-        interpret=interpret,
-    )(slabs)
-    return out.reshape(r * LANES)[:p]
-
-
-def _acc_dtype(x: jnp.ndarray):
-    return jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.float32
+from repro.kernels.tiling import (
+    LANES, SUBLANES, count_path, fit_block, nchw_from_rows, nhwc_rows, pad_to, round_up,
+    sublane_tile,
+)
 
 
 # ---------------------------------------------------------------------------
@@ -95,19 +81,6 @@ def _acc_dtype(x: jnp.ndarray):
 
 _MIN_TAP_C = LANES // 2   # one tap fills at least half of the MXU's contraction
 _X_BLOCK_BYTES = 1 << 19  # digits of one row block: Sx · rows · C bytes
-
-_PATH_COUNTS: Counter = Counter()
-
-
-def path_counts() -> Dict[str, int]:
-    """How many ``conv2d`` calls were traced on each path (``taps`` or
-    ``patches``) since :func:`reset_path_counts`."""
-    return dict(_PATH_COUNTS)
-
-
-def reset_path_counts() -> None:
-    _PATH_COUNTS.clear()
-
 
 def _digits(a: jnp.ndarray, n: int) -> List[jnp.ndarray]:
     """The ``n`` int8 digits ``bitslice_matmul.int_slices`` gives an integer
@@ -142,6 +115,16 @@ def _phase_taps(kh: int, kw: int, s: int, wq: int):
     taps = tuple((phases.index((ky % s, kx % s)), (ky // s) * wq + kx // s)
                  for ky in range(kh) for kx in range(kw))
     return phases, taps
+
+
+def _tile_rows(wq: int) -> int:
+    """The grid's row width: ``wq`` rounded up to a whole 8-row tile, so
+    that ``(N, Hq, Wq, C)`` and its ``(N·Hq·Wq, C)`` rows share one layout
+    and XLA pads the input and crops the output in place instead of
+    relaying them out channels-major; ``wq`` itself where that would add
+    more than a quarter of the rows (a 7×7 map's 9 → 16)."""
+    aligned = round_up(wq, 8)
+    return aligned if 4 * aligned <= 5 * wq else wq
 
 
 def _row_blocks(r: int, sx: int, bc: int, hb: int, cap: int) -> Tuple[int, int]:
@@ -258,10 +241,11 @@ def conv2d(
         nx = nw = 1
         pairs = ((0, 0),)
     path = "taps" if kh * kw == 1 or c >= _MIN_TAP_C else "patches"
-    _PATH_COUNTS[path] += 1
+    count_path("conv2d", path)
     with jax.named_scope(path):
         if path == "taps":
             hq, wq = -(-(h + 2 * pad) // s), -(-(hw + 2 * pad) // s)
+            wq = _tile_rows(wq)
             phases, taps = _phase_taps(kh, kw, s, wq)
             hi = (hq * s - h - pad, wq * s - hw - pad)
         else:
@@ -313,22 +297,84 @@ def int_matmul(
                        w_bits=w_bits, block=block, interpret=interpret)
 
 
+# ---------------------------------------------------------------------------
+# pools over channels-last rows
+# ---------------------------------------------------------------------------
+
+
+def _acc_dtype(x: jnp.ndarray):
+    return jnp.int32 if jnp.issubdtype(x.dtype, jnp.integer) else jnp.float32
+
+
+def _images_per_block(n: int, h: int, oh: int, lanes: int, in_dtype, out_dtype,
+                      block: int) -> int:
+    """Whole images per grid step, about ``block`` × 128 input elements:
+    a multiple of the count whose ``H`` input and ``OH`` output rows fill
+    whole tiles, or all ``n``."""
+    tin, tout = sublane_tile(in_dtype), sublane_tile(out_dtype)
+    unit = math.lcm(tin // math.gcd(h, tin), tout // math.gcd(oh, tout))
+    return min(n, max(1, block * LANES // (h * lanes) // unit) * unit)
+
+
+def _window_kernel(x_ref, o_ref, col_ref, *, op, images: int, h: int, oh: int, ow: int,
+                   c: int, window: Tuple[int, int], stride: Tuple[int, int]):
+    """The window reduction ``op`` of ``images`` whole images: x_ref
+    ``(images·H, W·C)``, o_ref ``(images·OH, OW·C)``, both channels-last
+    rows.  Rows first: each 128-lane column of an image is copied into
+    col_ref ``(H, 128)`` (Mosaic reads strided rows from a 128-lane ref
+    only) and its window rows ``sh·i + ky`` are read as KH strided views.
+    Then columns: the KW lane slices at ``(sw·j + kx)·C``, C wide."""
+    wc, (kh, kw), (sh, sw) = x_ref.shape[1], window, stride
+    for b in range(images):
+        cols = []
+        for q in range(0, wc, LANES):
+            width = min(LANES, wc - q)
+            col_ref[:, :width] = x_ref[b * h:(b + 1) * h, q:q + width].astype(col_ref.dtype)
+            views = [col_ref[pl.ds(ky, oh, stride=sh), :][:, :width] for ky in range(kh)]
+            cols.append(functools.reduce(op, views))
+        r = cols[0] if len(cols) == 1 else jnp.concatenate(cols, axis=1)
+        out = [functools.reduce(op, [r[:, (sw * j + kx) * c:(sw * j + kx + 1) * c]
+                                     for kx in range(kw)]) for j in range(ow)]
+        o_ref[b * oh:(b + 1) * oh, :] = jnp.concatenate(out, axis=1).astype(o_ref.dtype)
+
+
+def _window_pool(name: str, op, x: jnp.ndarray, window: Tuple[int, int],
+                 stride: Tuple[int, int], out_dtype, block: int, interpret: bool,
+                 finish=lambda rows: rows) -> jnp.ndarray:
+    """``(N, C, H, W)`` → ``(N, C, OH, OW)``: ``op`` over each ``(KH, KW)``
+    window of the channels-last rows in ``out_dtype``, then ``finish`` on
+    the result rows."""
+    n, c, h, w = x.shape
+    oh, ow = (h - window[0]) // stride[0] + 1, (w - window[1]) // stride[1] + 1
+    count_path(name, "channels_last")
+    bn = _images_per_block(n, h, oh, w * c, x.dtype, out_dtype, block)
+    with jax.named_scope("channels_last"):
+        rows = pl.pallas_call(
+            functools.partial(_window_kernel, op=op, images=bn, h=h, oh=oh, ow=ow, c=c,
+                              window=window, stride=stride),
+            grid=(pl.cdiv(n, bn),),
+            in_specs=[pl.BlockSpec((bn * h, w * c), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((bn * oh, ow * c), lambda i: (i, 0)),
+            out_shape=jax.ShapeDtypeStruct((n * oh, ow * c), out_dtype),
+            scratch_shapes=[pltpu.VMEM((h, LANES), _acc_dtype(x))],
+            interpret=interpret,
+        )(nhwc_rows(x))
+    return nchw_from_rows(finish(rows), n, c, oh, ow)
+
+
 @register_kernel("maxpool2d", oracle=ref.maxpool2d_ref)
 def maxpool2d(
     x: jnp.ndarray,
     *,
     window: int = 2,
     stride: Optional[int] = None,
-    block: int = 128,
+    block: int = 2048,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(N, C, H, W) → (N, C, OH, OW) window max (no padding)."""
     s = stride or window
-    n, c, h, w = x.shape
-    oh, ow = ref.conv2d_out_hw(h, w, window, window, s, 0)
-    patches = ref.pool_patches(x, window, s)
-    out = _blocked_pool(_pool_max_kernel, patches, x.dtype, block, interpret)
-    return out.reshape(n, c, oh, ow)
+    return _window_pool("maxpool2d", jnp.maximum, x, (window, window), (s, s), x.dtype, block,
+                        interpret)
 
 
 @register_kernel("avgpool2d", oracle=ref.avgpool2d_ref)
@@ -336,34 +382,26 @@ def avgpool2d(
     x: jnp.ndarray,
     *,
     window: int = 2,
-    block: int = 128,
+    block: int = 2048,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """(N, C, H, W) → (N, C, OH, OW) window average, stride == window.
 
     Integer inputs floor-divide by the window count — the semantics the
-    bit-serial machine gets for free by reading the sum accumulator at a
+    bit-serial machine gets for free by reading the accumulator at a
     wordline offset (an arithmetic right shift).
     """
-    n, c, h, w = x.shape
-    oh, ow = ref.conv2d_out_hw(h, w, window, window, window, 0)
-    patches = ref.pool_patches(x, window, window)
-    s = _blocked_pool(
-        functools.partial(_pool_sum_kernel, acc_dtype=_acc_dtype(x)),
-        patches, _acc_dtype(x), block, interpret,
-    )
-    return ref._pool_mean(s, window * window).reshape(n, c, oh, ow)
+    return _window_pool("avgpool2d", jnp.add, x, (window, window), (window, window),
+                        _acc_dtype(x), block, interpret,
+                        finish=lambda s: ref._pool_mean(s, window * window))
 
 
 @register_kernel("global_avgpool", oracle=ref.global_avgpool_ref)
 def global_avgpool(
-    x: jnp.ndarray, *, block: int = 128, interpret: bool = False
+    x: jnp.ndarray, *, block: int = 2048, interpret: bool = False
 ) -> jnp.ndarray:
-    """(N, C, H, W) → (N, C) spatial average (integer: floor-divide by H·W)."""
+    """(N, C, H, W) → (N, C) spatial average (integer: floor-divide by H·W):
+    the window pool whose one window is the whole map."""
     n, c, h, w = x.shape
-    flat = x.reshape(n * c, h * w)
-    s = _blocked_pool(
-        functools.partial(_pool_sum_kernel, acc_dtype=_acc_dtype(x)),
-        flat, _acc_dtype(x), block, interpret,
-    )
-    return ref._pool_mean(s, h * w).reshape(n, c)
+    return _window_pool("global_avgpool", jnp.add, x, (h, w), (h, w), _acc_dtype(x), block,
+                        interpret, finish=lambda s: ref._pool_mean(s, h * w)).reshape(n, c)

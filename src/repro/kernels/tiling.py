@@ -5,13 +5,25 @@ Mosaic accepts a block whose last two dimensions are multiples of the
 array dimension.  The kernels pick aligned blocks with :func:`fit_block`
 and zero-pad operands up to a whole number of blocks (slicing the result
 back), so awkward network shapes such as a 1000-class head tile exactly.
-Elementwise and pooling kernels see their operands as ``rows × 128`` lanes
-(:func:`lane_rows`) rather than as 1-D arrays, whose XLA layout (``T(1024)``)
+
+Between the ResNet kernels an activation is channels-last: the wrappers of
+the 4-D kernels see an ``(N, C, H, W)`` array as its ``(N·H, W·C)`` rows
+(:func:`nhwc_rows`, :func:`nchw_from_rows`).  W·C is lane-dense where C is
+not (C = 64 would pad every 128-lane tile by half): a multiple of 128 at
+every ResNet-18 stage, where NCHW's ``(8, 128)`` tiles over (H, W) pad W
+up to 128 lanes.  Each wrapper's closing transpose back
+to NCHW meets the next wrapper's opening one inside the Program's jit, and
+XLA folds the pair away.  Other ranks are flattened to ``rows × 128`` lanes
+(:func:`lane_rows`), not left 1-D: a 1-D array's XLA layout (``T(1024)``)
 Mosaic blocks cannot match.
+
+:func:`path_counts` counts, at trace time, which path each kernel call
+took (``conv2d/taps``, ``relu/channels_last``, ...).
 """
 from __future__ import annotations
 
-from typing import Sequence, Tuple
+from collections import Counter
+from typing import Dict, Sequence, Tuple
 
 import jax.numpy as jnp
 
@@ -46,9 +58,43 @@ def lane_rows(a: jnp.ndarray, block_rows: int) -> Tuple[jnp.ndarray, int]:
     return a.reshape(*a.shape[:-1], r, LANES), br
 
 
+def nhwc_rows(x: jnp.ndarray) -> jnp.ndarray:
+    """``(N, C, H, W)`` → its channels-last rows ``(N·H, W·C)``."""
+    n, c, h, w = x.shape
+    return x.transpose(0, 2, 3, 1).reshape(n * h, w * c)
+
+
+def nchw_from_rows(rows: jnp.ndarray, n: int, c: int, h: int, w: int) -> jnp.ndarray:
+    """The inverse of :func:`nhwc_rows`: ``(N·H, W·C)`` → ``(N, C, H, W)``."""
+    return rows.reshape(n, h, w, c).transpose(0, 3, 1, 2)
+
+
+def sublane_tile(dtype) -> int:
+    """Rows of one ``(rows, 128)`` tile of ``dtype``: 8 at 32 bits, 16 at
+    16, 32 at 8."""
+    return max(8, SUBLANES // jnp.dtype(dtype).itemsize)
+
+
 def load32(ref) -> jnp.ndarray:
     """Read a kernel ref, widening narrow integers to int32: the VPU has no
     8/16-bit integer ALU ops.  Callers cast results back to the output
     dtype, which wraps like the oracle."""
     x = ref[...]
     return x.astype(jnp.int32) if jnp.issubdtype(x.dtype, jnp.integer) else x
+
+
+_PATH_COUNTS: Counter = Counter()
+
+
+def count_path(kernel: str, path: str) -> None:
+    _PATH_COUNTS[f"{kernel}/{path}"] += 1
+
+
+def path_counts() -> Dict[str, int]:
+    """How many calls of each kernel were traced on each of its paths
+    (``"<kernel>/<path>"``) since :func:`reset_path_counts`."""
+    return dict(_PATH_COUNTS)
+
+
+def reset_path_counts() -> None:
+    _PATH_COUNTS.clear()

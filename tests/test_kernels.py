@@ -177,6 +177,46 @@ _AWKWARD = {
         "avgpool2d", (_full((2, 3, 10, 10), 19, -(1 << 28), 1 << 28),), {"window": 2}),
     "global_avgpool_15_rows": lambda: (
         "global_avgpool", (_full((3, 5, 4, 4), 20, -(1 << 27), 1 << 27),), {}),
+    # the channels-last paths (``tiling.nhwc_rows``): C = 64 and C = 3, int8
+    # and int32, wrap-prone values; row blocks that do not divide N·H
+    "relu_channels_last_c64_near_wrap": lambda: (
+        "relu", (_near_wrap((2, 64, 9, 9), 47),), {}),
+    "relu_channels_last_c64_int8": lambda: (
+        "relu", (_full((2, 64, 9, 9), 48, -128, 128).astype(jnp.int8),), {}),
+    "relu_channels_last_c3_near_wrap": lambda: (
+        "relu", (_near_wrap((3, 3, 7, 9), 49),), {}),
+    "relu_channels_last_c3_int8": lambda: (
+        "relu", (_full((3, 3, 7, 9), 50, -128, 128).astype(jnp.int8),), {}),
+    # 51·9 = 459 rows of 576 lanes: a 448-row block and a last one past N·H
+    "relu_channels_last_partial_row_block": lambda: (
+        "relu", (_near_wrap((51, 64, 9, 9), 51),), {}),
+    "ewise_add_channels_last_c64_near_wrap": lambda: (
+        "ewise_add", (_near_wrap((2, 64, 9, 9), 52), _near_wrap((2, 64, 9, 9), 53)), {}),
+    "ewise_add_channels_last_c64_int8_wraps": lambda: (
+        "ewise_add", (_full((2, 64, 9, 9), 54, -128, 128).astype(jnp.int8),
+                      _full((2, 64, 9, 9), 55, -128, 128).astype(jnp.int8)), {}),
+    "ewise_add_channels_last_c3_near_wrap": lambda: (
+        "ewise_add", (_near_wrap((3, 3, 7, 9), 56), _near_wrap((3, 3, 7, 9), 57)), {}),
+    "ewise_add_channels_last_c3_int8_wraps": lambda: (
+        "ewise_add", (_full((3, 3, 7, 9), 58, -128, 128).astype(jnp.int8),
+                      _full((3, 3, 7, 9), 59, -128, 128).astype(jnp.int8)), {}),
+    "maxpool2d_w2_odd_near_wrap": lambda: (
+        "maxpool2d", (_near_wrap((2, 5, 9, 11), 60),), {"window": 2}),
+    "maxpool2d_w3_s2_odd_c64": lambda: (
+        "maxpool2d", (_full((2, 64, 9, 11), 61),), {"window": 3, "stride": 2}),
+    "maxpool2d_w2_int8": lambda: (
+        "maxpool2d", (_full((2, 3, 9, 9), 62, -128, 128).astype(jnp.int8),), {"window": 2}),
+    # 10 images of 10 rows: 8 a block, the last block runs past N
+    "maxpool2d_images_past_n": lambda: (
+        "maxpool2d", (_near_wrap((10, 3, 10, 10), 63),), {"window": 2}),
+    "avgpool2d_w2_odd": lambda: (
+        "avgpool2d", (_full((2, 5, 9, 11), 64, -(1 << 28), 1 << 28),), {"window": 2}),
+    "avgpool2d_w3_odd_negative": lambda: (
+        "avgpool2d", (_full((2, 3, 11, 10), 65, -(1 << 20), 10),), {"window": 3}),
+    "global_avgpool_negative_sums": lambda: (
+        "global_avgpool", (_full((3, 5, 7, 7), 66, -(1 << 20), 10),), {}),
+    "global_avgpool_images_past_n": lambda: (
+        "global_avgpool", (_full((10, 512, 7, 7), 67, -(1 << 22), 1 << 22),), {}),
     "softmax_37_rows": lambda: (
         "softmax_fixedpoint", (_full((37, 19), 21, -3000, 3000),), {"in_frac": 7}),
     "kv_append_13_rows": lambda: (
@@ -213,13 +253,24 @@ def test_conv_digits_equal_int_slices(n):
     np.testing.assert_array_equal(np.stack(_digits(x, n)), np.asarray(int_slices(x, n)))
 
 
-def _conv_paths(x, w, **kwargs):
-    from repro.kernels import conv
+@pytest.mark.parametrize("wq,want", [(58, 64), (30, 32), (29, 32), (28, 32), (16, 16),
+                                     (15, 16), (8, 8), (7, 8), (9, 9), (4, 4), (3, 3)])
+def test_conv_grid_rows_fill_whole_tiles(wq, want):
+    """The conv's row width is rounded up to a whole 8-row tile unless that
+    adds more than a quarter of the rows."""
+    from repro.kernels.conv import _tile_rows
 
-    conv.reset_path_counts()
+    assert _tile_rows(wq) == want
+
+
+def _paths(fn, *args):
+    """The kernel paths (``tiling.path_counts``) tracing ``fn`` takes."""
+    from repro.kernels import tiling
+
+    tiling.reset_path_counts()
     with use_backend("interpret"):
-        jax.eval_shape(lambda x, w: api.conv2d(x, w, **kwargs), x, w)
-    return conv.path_counts()
+        jax.eval_shape(fn, *args)
+    return tiling.path_counts()
 
 
 @pytest.mark.parametrize("c,k,path", [(3, 3, "patches"), (32, 3, "patches"), (64, 3, "taps"),
@@ -227,7 +278,20 @@ def _conv_paths(x, w, **kwargs):
 def test_conv2d_path_follows_channels(c, k, path):
     x = jax.ShapeDtypeStruct((1, c, 8, 8), jnp.int32)
     w = jax.ShapeDtypeStruct((4, c, k, k), jnp.int32)
-    assert _conv_paths(x, w, padding=k // 2) == {path: 1}
+    assert _paths(lambda x, w: api.conv2d(x, w, padding=k // 2), x, w) == {f"conv2d/{path}": 1}
+
+
+@pytest.mark.parametrize("shape,path", [((2, 64, 9, 9), "channels_last"),
+                                        ((2, 3, 7, 9), "channels_last"),
+                                        ((37, 19), "lane_rows"), ((4, 7, 9), "lane_rows"),
+                                        ((945,), "lane_rows")])
+@pytest.mark.parametrize("kernel", ["relu", "ewise_add"])
+def test_elementwise_path_follows_rank(kernel, shape, path):
+    """A 4-D operand is mapped over its channels-last rows; any other rank
+    over rows of 128 lanes."""
+    x = jax.ShapeDtypeStruct(shape, jnp.int32)
+    fn = api.relu if kernel == "relu" else (lambda x: api.ewise_add(x, x))
+    assert _paths(fn, x) == {f"{kernel}/{path}": 1}
 
 
 def test_conv2d_lowers_without_gather_or_patch_matrix():
@@ -253,19 +317,41 @@ def test_conv2d_lowers_without_gather_or_patch_matrix():
 def test_resnet18_lowers_19_taps_1_patches_no_gather():
     """The ResNet-18 Program lowered for the TPU (nothing runs): the stem
     takes the patch slab, the 19 other convs the taps, and no conv gathers."""
-    from repro.kernels import conv
+    from repro.kernels import tiling
     from repro.models import resnet
 
     cfg = resnet.RESNET18
     params, x = jax.eval_shape(lambda: (resnet.init_params(cfg), resnet.make_input(cfg, batch=2)))
     traced = api.trace(lambda p, x: resnet.forward(cfg, p, x), name="resnet18")
-    conv.reset_path_counts()
+    tiling.reset_path_counts()
     with use_backend("pallas"):
         ex = api.compile(traced.trace(params, x))
         text = jax.jit(lambda p, x: ex(p, x)).trace(params, x).lower(
             lowering_platforms=("tpu",)).as_text()
-    assert conv.path_counts() == {"taps": 19, "patches": 1}
+    counts = tiling.path_counts()
+    assert {k: v for k, v in counts.items() if k.startswith("conv2d/")} == {
+        "conv2d/taps": 19, "conv2d/patches": 1}
     assert "gather" not in text
+
+
+def test_resnet18_activations_stay_channels_last():
+    """Every relu, residual add and pool of the benchmark's ResNet-18 forward
+    (112x112 images, a 2x2 stem max pool) takes the channels-last path: 17
+    relu, 8 add, 1 max pool and 1 global pool, so the transposes between
+    kernels cancel."""
+    import json
+
+    from chipbench.harness import BENCH, load_module
+    from repro.models import resnet
+
+    rn = load_module(BENCH / "configs" / "resnet18.py")
+    cfg = rn.program_config(json.loads((BENCH / "configs" / "resnet18.json").read_text()))
+    params, x = jax.eval_shape(lambda: (resnet.init_params(cfg), resnet.make_input(cfg, batch=2)))
+    traced = api.trace(lambda p, x: resnet.forward(cfg, p, x), name="resnet18")
+    counts = _paths(lambda p, x: api.compile(traced.trace(params, x))(p, x), params, x)
+    assert counts == {"conv2d/taps": 19, "conv2d/patches": 1, "relu/channels_last": 17,
+                      "ewise_add/channels_last": 8, "maxpool2d/channels_last": 1,
+                      "global_avgpool/channels_last": 1}
 
 
 def test_rglru_scan_padded_blocks():

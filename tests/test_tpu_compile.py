@@ -127,3 +127,52 @@ def test_kimi_decode_step_compiles_for_v5e(one_chip):
         params, cache, tokens).compile()
     m = compiled.memory_analysis()
     assert m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes < 16e9
+
+
+def test_resnet18_activations_stay_channels_last_on_v5e(one_chip):
+    """The benchmark's ResNet-18 forward (112x112 images, a 2x2 stem max
+    pool) compiled for v5e: outside the Pallas calls no instruction makes an
+    activation in its (N, C, H, W) shape, the form whose (8, 128) tiles pad W
+    up to 128 lanes (the program's input is the only one), none lays an
+    (N, H, W, C) activation out with C off the minor dimension (XLA's
+    channels-major relayout around a conv whose row width is no whole tile),
+    and nothing loops (the window matrix of the pool's old path filled a
+    ``while``).  The wrappers' transposes to and from channels-last rows
+    cancel."""
+    import json
+    import re
+
+    from chipbench.harness import BENCH, load_module
+    from repro.models import resnet
+
+    rn = load_module(BENCH / "configs" / "resnet18.py")
+    cfg = rn.program_config(json.loads((BENCH / "configs" / "resnet18.json").read_text()))
+    n = chip_smoke.RESNET_BATCH
+    params, x = jax.tree_util.tree_map(
+        lambda a: _struct(a, one_chip),
+        jax.eval_shape(lambda: (resnet.init_params(cfg), resnet.make_input(cfg, batch=n))),
+    )
+    traced = api.trace(lambda p, x: resnet.forward(cfg, p, x), name="resnet18")
+    with api.use_backend("pallas"):
+        ex = api.compile(traced.trace(params, x))
+    text = jax.jit(lambda p, x: ex(p, x)).lower(params, x).compile().as_text()
+    hw = cfg.input_hw
+    nchw = {(n, cfg.stem_channels, hw, hw)}
+    hw //= 2
+    for i, c in enumerate(cfg.stage_channels):
+        hw //= 2 if i else 1
+        nchw.add((n, c, hw, hw))
+    nhwc = {(n, h, w, c) for n, c, h, w in nchw}
+    made, channels_major = [], []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]+)\]\{([\d,]+)", line)
+        if m and "tpu_custom_call" not in line:
+            dims = tuple(int(d) for d in m.group(2).split(","))
+            if dims in nchw:
+                made.append(m.group(1))
+            if dims in nhwc and not m.group(3).startswith("3,"):
+                channels_major.append(m.group(1))
+    assert not made, made
+    assert not channels_major, channels_major
+    assert text.count("tpu_custom_call") >= len(resnet.layer_names(cfg))
+    assert " while(" not in text
