@@ -75,26 +75,18 @@ def _wrapped(module, name: str, layers: Optional[set], fault: Callable) -> Itera
 
 def mask_off(layers: Optional[set] = None):
     """Prefill attention without its causal mask."""
-    from repro.models import transformer
+    from repro.models import attention
 
-    return lambda: _wrapped(transformer, "full_attention", layers,
+    return lambda: _wrapped(attention, "full_attention", layers,
                             lambda orig, *a, **k: orig(*a, **{**k, "causal": False}))
 
 
 def kv_write_skipped(layers: Optional[set] = None):
     """A decode step that never writes the new token's row into the KV cache:
     its attention and the cache it returns both lack it."""
-    from repro.models import transformer
+    from repro.models import attention
 
-    def fault(orig, *args):
-        write = jax.lax.dynamic_update_slice_in_dim
-        jax.lax.dynamic_update_slice_in_dim = lambda operand, *_, **__: operand
-        try:
-            return orig(*args)
-        finally:
-            jax.lax.dynamic_update_slice_in_dim = write
-
-    return lambda: _wrapped(transformer, "_attn_decode", layers, fault)
+    return lambda: _wrapped(attention, "kv_write", layers, lambda orig, entry, *a: entry)
 
 
 def variants(n_layers: int) -> List[Variant]:

@@ -1,5 +1,7 @@
 """Attention: GQA full/causal, flash-style chunked (online softmax), windowed
-local, cross, and single-token decode.
+local, cross, and single-token decode; and the ``attn`` and ``local_attn``
+block kinds built on them (``BLOCK_KINDS``), with the encoder's bidirectional
+form and the decoder's cross-attention.
 
 Long sequences never materialize O(S^2) score tensors: ``chunked_attention``
 scans KV chunks carrying (max, denom, acc) — the standard online-softmax
@@ -17,13 +19,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.api import PrecisionSpec
+from repro.models.common import BlockKind, Params, apply_rope, dtype_of, linear, linear_init
 
 NEG_INF = -1e30
 
-
-def _split_heads(x: jnp.ndarray, n_heads: int) -> jnp.ndarray:
-    b, s, _ = x.shape
-    return x.reshape(b, s, n_heads, -1)
+# Decode-state precision (PIMSAB adaptive precision on the KV cache): the
+# int8 preset matches the MXU's native slice width — one plane pair per
+# score/readout contraction.  A future RunFlags lever can lower this.
+KV_SPEC = PrecisionSpec.int8
 
 
 def _gqa_fold(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
@@ -298,3 +301,142 @@ def decode_attention(q1, k_cache, v_cache, valid_len: Optional[jnp.ndarray] = No
     probs = jax.nn.softmax(scores, axis=-1).astype(q1.dtype)
     out = jnp.einsum("bhgt,bthd->bhgd", probs, v_cache)
     return out.reshape(b, 1, hq, d)
+
+
+# ---------------------------------------------------------------------------
+# the attention block kinds: global (``attn``) and windowed (``local_attn``,
+# whose decode cache is a ring buffer of ``cfg.window`` positions)
+# ---------------------------------------------------------------------------
+
+
+def attn_init(key, cfg, dtype) -> Params:
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": linear_init(ks[0], cfg.d_model, cfg.q_dim, dtype, bias=cfg.qkv_bias),
+        "wk": linear_init(ks[1], cfg.d_model, cfg.kv_dim, dtype, bias=cfg.qkv_bias),
+        "wv": linear_init(ks[2], cfg.d_model, cfg.kv_dim, dtype, bias=cfg.qkv_bias),
+        "wo": linear_init(ks[3], cfg.q_dim, cfg.d_model, dtype),
+    }
+
+
+def _qkv(p: Params, x: jnp.ndarray, cfg):
+    """x (B, S, D) -> per-head q (B,S,Hq,hd), k and v (B,S,Hkv,hd), unrotated."""
+    b, s, _ = x.shape
+    hd = cfg.resolved_head_dim
+    return (linear(p["wq"], x).reshape(b, s, cfg.n_heads, hd),
+            linear(p["wk"], x).reshape(b, s, cfg.n_kv_heads, hd),
+            linear(p["wv"], x).reshape(b, s, cfg.n_kv_heads, hd))
+
+
+def _attn_seq(p: Params, x: jnp.ndarray, cfg, flags, positions: jnp.ndarray,
+              window: bool = False, causal: bool = True):
+    b, s, _ = x.shape
+    q, k, v = _qkv(p, x, cfg)
+    q, k = apply_rope(q, positions, cfg.rope_theta), apply_rope(k, positions, cfg.rope_theta)
+    if not window:
+        out = full_attention(q, k, v, causal=causal, chunk=flags.attn_chunk,
+                             triangular=flags.triangular_attn,
+                             flash_threshold=flags.flash_threshold)
+    elif s <= 2 * cfg.window and s <= flags.flash_threshold:
+        out = local_attention(q, k, v, cfg.window)  # small-S direct band
+    else:
+        out = full_attention(q, k, v, causal=causal, chunk=min(flags.attn_chunk, cfg.window),
+                             triangular=flags.triangular_attn,
+                             flash_threshold=0,  # always banded-chunked
+                             window=cfg.window)
+    return linear(p["wo"], out.reshape(b, s, cfg.q_dim)), {"k": k, "v": v}
+
+
+def _kv_len(cfg, max_len: int, window: bool) -> int:
+    return min(cfg.window, max_len) if window else max_len
+
+
+def _kv_cache(cfg, batch: int, max_len: int, flags, window: bool = False) -> Params:
+    shp = (batch, _kv_len(cfg, max_len, window), cfg.n_kv_heads, cfg.resolved_head_dim)
+    if flags.quant_kv:
+        # PIMSAB adaptive precision on state: int8 payload + per-(b,t,h)
+        # scales; scores/readout run on the integer path (bit-serial attn)
+        return {"k": jnp.zeros(shp, jnp.int8), "v": jnp.zeros(shp, jnp.int8),
+                "k_scale": jnp.zeros(shp[:3], jnp.float32),
+                "v_scale": jnp.zeros(shp[:3], jnp.float32)}
+    return {"k": jnp.zeros(shp, dtype_of(cfg)), "v": jnp.zeros(shp, dtype_of(cfg))}
+
+
+def _kv_to_decode(entries: Params, cfg, s: int, max_len: int, flags,
+                  window: bool = False) -> Params:
+    """The last ``w`` positions' keys and values (B, w, Hkv, hd), or all of
+    them padded to ``w``; int8 with scales under ``flags.quant_kv``."""
+    w = _kv_len(cfg, max_len, window)
+
+    def fit(kv):
+        return kv[:, s - w:s] if s >= w else jnp.pad(kv, ((0, 0), (0, w - s), (0, 0), (0, 0)))
+
+    out = {"k": fit(entries["k"]), "v": fit(entries["v"])}
+    if flags.quant_kv:
+        for n in ("k", "v"):
+            out[n], out[f"{n}_scale"] = quantize_kv(out[n], KV_SPEC)
+    return out
+
+
+def kv_write(entry: Params, k: jnp.ndarray, v: jnp.ndarray, slot) -> Params:
+    """The decode step's cache write: the new token's keys and values (B, 1,
+    Hkv, hd) at ``slot``, quantized first in an int8 cache."""
+    with jax.named_scope("kv_write"):
+        rows = {"k": k, "v": v}
+        if "k_scale" in entry:  # int8 KV cache (PIMSAB adaptive precision)
+            (k, k_scale), (v, v_scale) = quantize_kv(k, KV_SPEC), quantize_kv(v, KV_SPEC)
+            rows = {"k": k, "v": v, "k_scale": k_scale, "v_scale": v_scale}
+        return {**entry, **{n: jax.lax.dynamic_update_slice_in_dim(entry[n], row, slot, axis=1)
+                            for n, row in rows.items()}}
+
+
+def _attn_decode(p: Params, h: jnp.ndarray, cfg, entry: Params, pos, window: bool = False):
+    b = h.shape[0]
+    q, k, v = _qkv(p, h, cfg)
+    posb = jnp.full((b, 1), pos, jnp.int32)
+    q, k = apply_rope(q, posb, cfg.rope_theta), apply_rope(k, posb, cfg.rope_theta)
+    if window:
+        # ring buffer: all slots < valid are live (order irrelevant w/ RoPE
+        # applied at insert time)
+        w = entry["k"].shape[1]
+        slot, valid = pos % w, jnp.minimum(pos + 1, w) * jnp.ones((b,), jnp.int32)
+    else:
+        slot, valid = pos, (pos + 1) * jnp.ones((b,), jnp.int32)
+    new = kv_write(entry, k, v, slot)
+    if "k_scale" in new:
+        out = decode_attention_int8(q, new["k"], new["v"], new["k_scale"], new["v_scale"],
+                                    valid, KV_SPEC)
+    else:
+        out = decode_attention(q, new["k"], new["v"], valid)
+    return linear(p["wo"], out.reshape(b, 1, cfg.q_dim)), new
+
+
+def _attention_kind(window: bool) -> BlockKind:
+    return BlockKind(param="attn", scope="attn", ffn=True, init=attn_init,
+                     seq=partial(_attn_seq, window=window),
+                     cache=partial(_kv_cache, window=window),
+                     to_decode=partial(_kv_to_decode, window=window),
+                     decode=partial(_attn_decode, window=window))
+
+
+BLOCK_KINDS = {"attn": _attention_kind(False), "local_attn": _attention_kind(True)}
+
+# an encoder's blocks: global attention without the causal mask
+BIDIRECTIONAL = BLOCK_KINDS["attn"]._replace(seq=partial(_attn_seq, causal=False))
+
+
+def cross_kv(p: Params, enc_out: jnp.ndarray, cfg) -> Params:
+    """The encoder output's keys and values (B, T, Hkv, hd) for one block."""
+    b, t, _ = enc_out.shape
+    hd = cfg.resolved_head_dim
+    return {"k": linear(p["wk"], enc_out).reshape(b, t, cfg.n_kv_heads, hd),
+            "v": linear(p["wv"], enc_out).reshape(b, t, cfg.n_kv_heads, hd)}
+
+
+def cross_attention(p: Params, x: jnp.ndarray, kv: Params, cfg) -> jnp.ndarray:
+    """x (B, S, D), a sequence or a decode step, attending to ``kv``."""
+    b, s, _ = x.shape
+    q = linear(p["wq"], x).reshape(b, s, cfg.n_heads, cfg.resolved_head_dim)
+    out = full_attention(q, kv["k"], kv["v"], causal=False, chunk=2048, triangular=False,
+                         flash_threshold=8192)
+    return linear(p["wo"], out.reshape(b, s, cfg.q_dim))

@@ -11,7 +11,7 @@ results are recombined with shifts.  Adaptive precision = fewer slices;
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +21,35 @@ from repro.kernels import api
 from repro.kernels.api import PrecisionSpec, SlicedTensor
 
 Params = Dict[str, Any]
+
+
+class BlockKind(NamedTuple):
+    """One temporal-mixer kind of a transformer block (a name in
+    ``ModelConfig.block_pattern``): every decision the model makes by kind.
+    ``h`` is the block's normed input, (B, S, D) or a decode step's (B, 1, D).
+
+    * ``init(key, cfg, dtype)`` -> the mixer's parameters, kept under
+      ``param`` in the block; with ``ffn`` the block also carries an FFN
+      (when ``cfg.d_ff`` > 0);
+    * ``seq(params, h, cfg, flags, positions)`` -> ``(y, entries)``: the
+      sequence form, causal over ``positions`` (1, S), with the raw cache
+      entries of every position;
+    * ``cache(cfg, batch, max_len, flags)`` -> the empty decode-cache entry;
+    * ``to_decode(entries, cfg, s, max_len, flags)`` -> the entries a prefill
+      of ``s`` positions keeps, in the decode layout;
+    * ``decode(params, h, cfg, entry, pos)`` -> ``(y, new entry)``: the decode
+      form, one token at position ``pos``.
+
+    Both forms trace under the name scope ``scope`` when it is set."""
+
+    param: str
+    scope: Optional[str]
+    ffn: bool
+    init: Callable
+    seq: Callable
+    cache: Callable
+    to_decode: Callable
+    decode: Callable
 
 
 def dtype_of(cfg) -> jnp.dtype:

@@ -15,6 +15,7 @@ name scope ``mla_expand``.  The decode form absorbs ``W_kvb`` into the query
 and the output (``mla_absorb``): it attends over the latent cache directly
 and never expands it.  ``W_kvb`` multiplies the latent unquantized (its
 weight may be stored int8 with scales), so both forms agree up to rounding.
+``BLOCK_KINDS`` holds the ``mla`` block kind built on them.
 """
 from __future__ import annotations
 
@@ -25,8 +26,10 @@ import jax.numpy as jnp
 
 from repro.models.attention import NEG_INF, full_attention
 from repro.models.common import (
+    BlockKind,
     Params,
     dequantized,
+    dtype_of,
     linear,
     linear_init,
     rmsnorm,
@@ -55,6 +58,17 @@ def cache_entry(cfg, batch: int, max_len: int, dtype) -> Dict[str, jnp.ndarray]:
     m = cfg.mla
     return {"c_kv": jnp.zeros((batch, max_len, m.kv_lora_rank), dtype),
             "k_pe": jnp.zeros((batch, max_len, m.qk_rope_head_dim), dtype)}
+
+
+def _empty_entry(cfg, batch: int, max_len: int, flags) -> Dict[str, jnp.ndarray]:
+    if flags.quant_kv:
+        raise ValueError("latent attention keeps a bfloat16 latent cache (quant_kv unsupported)")
+    return cache_entry(cfg, batch, max_len, dtype_of(cfg))
+
+
+def _to_decode(entries: Params, cfg, s: int, max_len: int, flags) -> Dict[str, jnp.ndarray]:
+    """The prompt's latents and rotary keys, padded to ``max_len`` positions."""
+    return {n: jnp.pad(entries[n], ((0, 0), (0, max_len - s), (0, 0))) for n in ("c_kv", "k_pe")}
 
 
 def softmax_scale(cfg) -> float:
@@ -150,3 +164,8 @@ def mla_decode(p: Params, x: jnp.ndarray, cfg, entry: Params, pos) -> Tuple[jnp.
         out = jnp.einsum("bhc,chm->bhm", o_lat, wv)
     y = linear(p["wo"], out.reshape(b, 1, -1).astype(x.dtype))
     return y, new
+
+
+BLOCK_KINDS = {"mla": BlockKind(param="attn", scope="attn", ffn=True, init=mla_init,
+                                seq=mla_attention, cache=_empty_entry, to_decode=_to_decode,
+                                decode=mla_decode)}

@@ -11,6 +11,9 @@ two execution forms:
   ``lax.scan`` over time.
 * single-step form (decode): carries a fixed-size state — this is what makes
   the ``long_500k`` cell tractable for these families.
+
+``BLOCK_KINDS`` holds the three block kinds: each ``*_block_apply`` serves
+both forms, and the state it returns is the block's cache entry.
 """
 from __future__ import annotations
 
@@ -20,7 +23,7 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.models.common import Params, dense_init, linear, linear_init
+from repro.models.common import BlockKind, Params, dense_init, linear, linear_init
 
 # ---------------------------------------------------------------------------
 # causal conv1d (width-K depthwise), used by RG-LRU and mLSTM blocks
@@ -335,3 +338,27 @@ def slstm_state_init(cfg, batch: int) -> Dict:
     h, dh = cfg.n_heads, cfg.d_model // cfg.n_heads
     z = jnp.zeros((batch, h, dh), jnp.float32)
     return {"c": z, "n": z + 1.0, "h": z, "m": z}
+
+
+# ---------------------------------------------------------------------------
+# block kinds
+# ---------------------------------------------------------------------------
+
+
+def _recurrent_kind(init, apply, state_init, ffn: bool, seq_kwargs=lambda flags: {}) -> BlockKind:
+    return BlockKind(
+        param="mixer", scope=None, ffn=ffn, init=init,
+        seq=lambda p, h, cfg, flags, positions: apply(p, h, cfg, **seq_kwargs(flags)),
+        cache=lambda cfg, batch, max_len, flags: state_init(cfg, batch),
+        to_decode=lambda entries, cfg, s, max_len, flags: entries,
+        decode=lambda p, h, cfg, entry, pos: apply(p, h, cfg, entry),
+    )
+
+
+BLOCK_KINDS = {
+    "rglru": _recurrent_kind(rglru_block_init, rglru_block_apply, rglru_state_init, ffn=True),
+    # the chunkwise form's chunk: the attention chunk, at most 256 positions
+    "mlstm": _recurrent_kind(mlstm_block_init, mlstm_block_apply, mlstm_full_state_init,
+                             ffn=False, seq_kwargs=lambda flags: {"chunk": min(flags.attn_chunk, 256)}),
+    "slstm": _recurrent_kind(slstm_block_init, slstm_block_apply, slstm_state_init, ffn=False),
+}
